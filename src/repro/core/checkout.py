@@ -525,11 +525,18 @@ class StateLoader:
         for in-place numpy patches)."""
         base_info = p.manifest["base"]
         chunks = base_info["chunks"]
+        bad = [i for i in p.dirty
+               if len(got.get(chunks[i]["key"], b"")) != int(chunks[i]["n"])]
+        if bad:
+            raise ChunkMissingError(
+                f"{key_str(p.key)}@{p.version}: patch chunk "
+                f"{chunks[bad[0]]['key']} missing or short")
         segs = [(p.offsets[i], got[chunks[i]["key"]]) for i in p.dirty]
         if p.is_device:
             # fused scatter first: one compacted upload + one kernel pass
-            # for ALL dirty chunks of this co-variable; falls back to the
-            # per-chunk dynamic_update_slice loop (same bytes, K dispatches)
+            # for ALL dirty chunks of this co-variable; where it does not
+            # apply, the per-chunk dynamic_update_slice loop (same bytes,
+            # K dispatches)
             chunk_bytes = int(chunks[0]["n"]) if len(chunks) > 1 else 0
             fused = delta_mod.patch_device_chunks(p.base, segs, chunk_bytes)
             if fused is not None:
@@ -644,14 +651,15 @@ class StateLoader:
                         covs=len(full_items) + len(replay_items)):
             loaded = self._materialize_mixed(full_items, replay_items, stats)
 
-        # 3. apply patches (all data is in hand); unexpected failures fall
-        #    back to the full serial load of just that co-variable
+        # 3. apply patches (all data is in hand); a missing or short patch
+        #    chunk falls back to the full serial load of just that
+        #    co-variable — any other error (a device kernel's) raises
         with self._span("patch", covs=len(patches)):
             for p in patches:
                 try:
                     loaded[p.key] = self._apply_patch(p, patch_data, stats,
                                                       tracked_ns.base)
-                except Exception as e:  # noqa: BLE001 — corrupt patch:
+                except ChunkMissingError as e:
                     delta_mod.note_kernel_fallback("apply_patch", e)
                     loaded[p.key] = self.load_cov(p.key, p.version, stats)
 

@@ -101,9 +101,9 @@ class RecordBuilder:
                     self.hashed_bytes += pack.nbytes
                     cache[key] = pack.hashes
                     return pack.hashes
-                # device arrays: hash on device (Pallas chunk_hash kernel,
-                # jnp fallback) so delta *detection* doesn't transfer the
-                # whole buffer host-side; None -> host path below
+                # device arrays: hash on device (the chunk_hash kernel)
+                # so delta *detection* doesn't transfer the whole buffer
+                # host-side; None -> host path below
                 h = hashing.chunk_hashes_device(base, self.chunk_bytes)
                 if h is not None:
                     self.hash_calls += 1

@@ -68,6 +68,17 @@ def note_kernel_fallback(where: str, err: Exception) -> None:
             where, type(err).__name__, err)
 
 
+def note_kernel_call(kernel: str, backend: str) -> None:
+    """Count one dispatch of a device kernel by the backend that ran it
+    (``kishu_kernel_calls_total{kernel,backend}`` in the active session's
+    registry) — how a run shows whether the Pallas kernel or the jnp
+    reference did the work."""
+    o = _active_obs()
+    if o is not None:
+        o.registry.counter("kishu_kernel_calls_total", kernel=kernel,
+                           backend=backend).inc()
+
+
 def kernel_fallbacks() -> int:
     """Total device-kernel fallbacks — scoped to the active session's
     metrics registry when one is executing; otherwise the (deprecated)
@@ -158,17 +169,34 @@ def range_reader(base: Any, chunk_bytes: int) -> Optional[Callable[[int, int], b
 # fused on-device delta pack (writer side, DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
-def device_delta_pack(base: Any, prev_hashes, chunk_bytes: int):
-    """One fused Pallas pass over a device array: detection hashes, dirty
-    indices, and a *compacted* dirty-chunk buffer still on device — only
-    dirty bytes ever cross device→host (``DeltaPack.read_chunks``).
+def device_kernel_applies(base: Any) -> bool:
+    """Whether the chunk kernels can take ``base``: a jax array (not a PRNG
+    key) held whole by one device, of a dtype the word bitcast handles and
+    with at least one byte.  Anything else takes the host path."""
+    import jax
 
-    Returns ``None`` whenever the fused path doesn't apply — host array,
-    PRNG key, non-power-of-two chunking, no/mismatched previous hashes, or
-    no working kernel backend — and the caller degrades one rung down the
-    ladder (``chunk_hashes_device`` → ``chunk_hashes_np`` + range_reader).
-    Only engaged off-CPU by default — on CPU interpret-mode dispatch loses
-    to NumPy — override with ``KISHU_DEVICE_DELTA=1/0``.
+    from repro.core.serialize import is_prng_key
+    from repro.kernels.chunk_hash.ops import words_supported
+
+    # an array sharded or replicated over several devices is refused here,
+    # explicitly: the kernels work on one array on one device
+    return (isinstance(base, jax.Array) and not is_prng_key(base)
+            and len(base.sharding.device_set) == 1
+            and words_supported(base.dtype) and base.size > 0)
+
+
+def device_delta_pack(base: Any, prev_hashes, chunk_bytes: int):
+    """One fused pass over a device array: detection hashes, dirty indices,
+    and a *compacted* dirty-chunk buffer still on device — only dirty bytes
+    ever cross device→host (``DeltaPack.read_chunks``).  The Pallas kernel
+    runs on a TPU, the jnp reference elsewhere.
+
+    Returns ``None`` whenever the fused path doesn't apply to the input —
+    not a one-device jax array (``device_kernel_applies``), non-power-of-two
+    chunking, no/mismatched previous hashes — and the caller hashes another
+    way (``chunk_hashes_device`` → ``chunk_hashes_np`` + range_reader).  A
+    kernel error raises.  Only engaged off-CPU by default — on CPU the
+    NumPy path is faster — override with ``KISHU_DEVICE_DELTA=1/0``.
     """
     if prev_hashes is None or chunk_bytes % 4 \
             or chunk_bytes & (chunk_bytes - 1):
@@ -178,29 +206,25 @@ def device_delta_pack(base: Any, prev_hashes, chunk_bytes: int):
         return None
     import jax
 
-    from repro.core.serialize import is_prng_key
-
     if env != "1" and jax.default_backend() == "cpu":
         return None
-    if not isinstance(base, jax.Array) or is_prng_key(base):
+    if not device_kernel_applies(base):
         return None
     nbytes = int(base.size) * np.dtype(base.dtype).itemsize
-    if nbytes <= 0:
-        return None
     n_chunks = -(-nbytes // chunk_bytes)
     prev = np.asarray(prev_hashes, dtype=np.uint64).reshape(-1)
     if prev.shape[0] != n_chunks:
         return None                      # structure changed: no valid diff
+    from repro.kernels.common import platform_backend
+    from repro.kernels.delta_pack.ops import delta_pack
+
     o = _active_obs()
     span = o.span("delta_pack", nbytes=nbytes) if o is not None \
         else contextlib.nullcontext()
+    backend = platform_backend(base)
+    note_kernel_call("delta_pack", backend)
     with span:
-        try:
-            from repro.kernels.delta_pack.ops import delta_pack_auto
-            return delta_pack_auto(base, prev, chunk_bytes)
-        except Exception as e:  # noqa: BLE001 — no kernel backend: host path
-            note_kernel_fallback("device_delta_pack", e)
-            return None
+        return delta_pack(base, prev, chunk_bytes, backend=backend)
 
 
 # ---------------------------------------------------------------------------
@@ -220,15 +244,16 @@ def patch_numpy_base(base: np.ndarray, segs: Sequence[Tuple[int, bytes]]
 def patch_device_chunks(base: Any, segs: Sequence[Tuple[int, bytes]],
                         chunk_bytes: int) -> Optional[Tuple[Any, int]]:
     """Fused checkout scatter: upload all dirty chunks of a device array as
-    one compacted buffer and land them in a single Pallas pass
-    (kernels/patch_scatter) — the mirror image of ``device_delta_pack``.
+    one compacted buffer and land them in a single pass
+    (kernels/patch_scatter: the Pallas kernel on a TPU, the jnp reference
+    elsewhere) — the mirror image of ``device_delta_pack``.
 
     Returns ``(patched array, bytes moved host→device)``, or ``None``
-    whenever the fused path doesn't apply — host array, PRNG key,
-    non-chunk-aligned segments, unsupported dtype, codec/env veto, or no
-    working backend — and the caller degrades to the per-chunk
-    ``patch_device_array`` loop below.  Only engaged off-CPU by default
-    (interpret-mode dispatch loses to the jnp loop on CPU); override with
+    whenever the fused path doesn't apply to the input — not a one-device
+    jax array of a word-bitcastable dtype, non-chunk-aligned or partial
+    segments, or the env veto — and the caller patches with the per-chunk
+    ``patch_device_array`` loop below.  A kernel error raises.  Only
+    engaged off-CPU by default (the jnp loop wins on CPU); override with
     ``KISHU_DEVICE_SCATTER=1/0``.
     """
     if not segs or chunk_bytes <= 0 or chunk_bytes % 4:
@@ -238,15 +263,11 @@ def patch_device_chunks(base: Any, segs: Sequence[Tuple[int, bytes]],
         return None
     import jax
 
-    from repro.core.serialize import is_prng_key
-
     if env != "1" and jax.default_backend() == "cpu":
         return None
-    if not isinstance(base, jax.Array) or is_prng_key(base):
+    if not device_kernel_applies(base):
         return None
     nbytes = int(base.size) * np.dtype(base.dtype).itemsize
-    if nbytes <= 0:
-        return None
     n_chunks = -(-nbytes // chunk_bytes)
     idx: List[int] = []
     blobs: List[bytes] = []
@@ -259,16 +280,17 @@ def patch_device_chunks(base: Any, segs: Sequence[Tuple[int, bytes]],
             return None                  # partial chunk: DUS path
         idx.append(i)
         blobs.append(data)
+    from repro.kernels.common import platform_backend
+    from repro.kernels.patch_scatter.ops import scatter_chunks
+
     o = _active_obs()
     span = o.span("scatter_dev", chunks=len(idx)) if o is not None \
         else contextlib.nullcontext()
+    backend = platform_backend(base)
+    note_kernel_call("patch_scatter", backend)
     with span:
-        try:
-            from repro.kernels.patch_scatter.ops import scatter_chunks_auto
-            out, moved = scatter_chunks_auto(base, idx, blobs, chunk_bytes)
-        except Exception as e:  # noqa: BLE001 — no backend: DUS path
-            note_kernel_fallback("patch_device_chunks", e)
-            return None
+        out, moved = scatter_chunks(base, idx, blobs, chunk_bytes,
+                                    backend=backend)
     if o is not None:
         try:
             o.registry.counter("kishu_h2d_bytes_total").inc(moved)
@@ -312,19 +334,17 @@ def patch_device_array(base: Any, segs: Sequence[Tuple[int, bytes]]) -> Any:
 
 def exact_dirty_indices(a: Any, b: Any, chunk_bytes: int) -> List[int]:
     """Chunk indices where ``a`` and ``b`` differ bitwise — the exact
-    (collision-free) answer the detection hashes approximate.  Uses the
-    ``block_diff`` Pallas kernel for device arrays (jnp ref, then NumPy
-    byte-compare as fallbacks); used by tests and paranoid verification to
+    (collision-free) answer the detection hashes approximate.  Device
+    arrays the kernels take are compared on device by ``block_diff`` (the
+    Pallas kernel on a TPU, the jnp reference elsewhere); anything else by
+    a NumPy byte compare.  Used by tests and paranoid verification to
     cross-check hash-planned deltas."""
-    import jax
-
-    if isinstance(a, jax.Array) and isinstance(b, jax.Array) \
+    if device_kernel_applies(a) and device_kernel_applies(b) \
             and chunk_bytes % 4 == 0 and chunk_bytes & (chunk_bytes - 1) == 0:
-        try:
-            from repro.kernels.block_diff.ops import dirty_chunks
-            return [int(i) for i in dirty_chunks(a, b, chunk_bytes)]
-        except Exception as e:  # noqa: BLE001 — kernel unavailable:
-            note_kernel_fallback("exact_dirty_indices", e)  # host compare
+        from repro.kernels.block_diff.ops import dirty_chunks
+        from repro.kernels.common import platform_backend
+        note_kernel_call("block_diff", platform_backend(a))
+        return [int(i) for i in dirty_chunks(a, b, chunk_bytes)]
     ba = np.ascontiguousarray(np.asarray(a)).reshape(-1).view(np.uint8)
     bb = np.ascontiguousarray(np.asarray(b)).reshape(-1).view(np.uint8)
     if ba.size != bb.size:

@@ -124,27 +124,31 @@ def chunk_hashes_device(x, chunk_bytes: int = DEFAULT_CHUNK_BYTES
                         ) -> Optional[np.ndarray]:
     """Detection hashes of a *device* array without a host round-trip.
 
-    Dispatches to the Pallas ``chunk_hash`` kernel (HBM-bandwidth path),
-    degrading to the jnp oracle and finally to ``None`` (caller hashes on
-    host via :func:`chunk_hashes_np`).  Only engaged off-CPU by default —
-    on CPU the NumPy path is faster than jit dispatch — override with
-    ``KISHU_DEVICE_HASH=1/0``.  Bit-identical to ``chunk_hashes_np`` by the
-    kernel contract (tested).
+    Runs the ``chunk_hash`` kernel (the Pallas kernel on a TPU, the jnp
+    oracle elsewhere).  Returns ``None`` when the input is not for it — a
+    non-power-of-two chunk, or not a one-device jax array of a
+    word-bitcastable dtype (``delta.device_kernel_applies``) — and the
+    caller hashes on host via :func:`chunk_hashes_np`.  A kernel error
+    raises.  Only engaged off-CPU by default — on CPU the NumPy path is
+    faster than jit dispatch — override with ``KISHU_DEVICE_HASH=1/0``.
+    Bit-identical to ``chunk_hashes_np`` by the kernel contract (tested).
     """
     if chunk_bytes % 4 or chunk_bytes & (chunk_bytes - 1):
         return None                 # kernel wants a power-of-two chunk
     env = os.environ.get("KISHU_DEVICE_HASH", "").strip()
     if env == "0":
         return None
-    if env != "1":
-        import jax
-        if jax.default_backend() == "cpu":
-            return None
-    try:
-        from repro.kernels.chunk_hash.ops import chunk_hash_u64_auto
-        return chunk_hash_u64_auto(x, chunk_bytes)
-    except Exception:  # noqa: BLE001 — no device backend: host path
+    import jax
+    if env != "1" and jax.default_backend() == "cpu":
         return None
+    from repro.core.delta import device_kernel_applies, note_kernel_call
+    if not device_kernel_applies(x):
+        return None
+    from repro.kernels.chunk_hash.ops import chunk_hash_u64
+    from repro.kernels.common import platform_backend
+    backend = platform_backend(x)
+    note_kernel_call("chunk_hash", backend)
+    return chunk_hash_u64(x, chunk_bytes, backend=backend)
 
 
 def combine_u64(lanes) -> np.ndarray:

@@ -2,9 +2,10 @@
 arrays (the undo-path fast check — both versions in device memory, so a
 bitwise compare is cheaper and exact vs hashing one side).
 
-Grid: one program per chunk; streams (1, W) uint32 blocks of both inputs
-HBM->VMEM, reduces `any(a != b)` on the VPU, writes one int32 flag.
-Bandwidth-bound by design: 2 streams in, 4 bytes out per chunk.
+Grid: one program per chunk; streams the (R, 128) word tiles of both
+inputs (``kernels/common.py``) HBM->VMEM, reduces `any(a != b)` on the
+VPU, and writes the flag across one (1, 128) output row.  Bandwidth-bound
+by design: 2 streams in, one row out per chunk.
 """
 from __future__ import annotations
 
@@ -14,12 +15,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import LANES, tile_words
+
 
 def _block_diff_kernel(a_ref, b_ref, out_ref):
-    a = a_ref[...]                                    # (1, W) uint32
-    b = b_ref[...]
-    neq = (a != b).astype(jnp.int32)
-    out_ref[0, 0] = jnp.max(neq)
+    neq = (a_ref[0] != b_ref[0]).astype(jnp.int32)
+    out_ref[0] = jnp.full((1, LANES), jnp.max(neq), jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -27,16 +28,15 @@ def block_diff_pallas(a_words: jax.Array, b_words: jax.Array, *,
                       interpret: bool = False) -> jax.Array:
     """a/b: uint32 [n_chunks, W]. Returns int32 [n_chunks]."""
     assert a_words.shape == b_words.shape, (a_words.shape, b_words.shape)
-    n_chunks, wsize = a_words.shape
+    a, b = tile_words(a_words), tile_words(b_words)
+    n_chunks, rows, _ = a.shape
+    spec = pl.BlockSpec((1, rows, LANES), lambda i: (i, 0, 0))
     out = pl.pallas_call(
         _block_diff_kernel,
         grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, wsize), lambda i: (i, 0)),
-            pl.BlockSpec((1, wsize), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, 1), jnp.int32),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_chunks, 1, LANES), jnp.int32),
         interpret=interpret,
-    )(a_words, b_words)
-    return out[:, 0]
+    )(a, b)
+    return out[:, 0, 0]

@@ -5,12 +5,12 @@ import functools
 from typing import Literal
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.block_diff.kernel import block_diff_pallas
 from repro.kernels.block_diff.ref import block_diff_ref
-from repro.kernels.chunk_hash.ops import _to_words
+from repro.kernels.chunk_hash.ops import chunk_rows
+from repro.kernels.common import platform_backend
 
 
 @functools.partial(jax.jit,
@@ -25,38 +25,16 @@ def block_diff(a: jax.Array, b: jax.Array, chunk_bytes: int = 1 << 18, *,
     """
     assert a.shape == b.shape and a.dtype == b.dtype, "structure mismatch"
     assert chunk_bytes % 4 == 0 and chunk_bytes & (chunk_bytes - 1) == 0
-    nbytes_total = a.size * np.dtype(a.dtype).itemsize
-    wa, wb = _to_words(a), _to_words(b)
-    wpc = chunk_bytes // 4
-    n_chunks = max(-(-int(nbytes_total) // chunk_bytes), 1)
-    pad = n_chunks * wpc - wa.shape[0]
-    if pad:
-        zeros = jnp.zeros((pad,), jnp.uint32)
-        wa = jnp.concatenate([wa, zeros])
-        wb = jnp.concatenate([wb, zeros])
-    wa = wa.reshape(n_chunks, wpc)
-    wb = wb.reshape(n_chunks, wpc)
+    wa, wb = chunk_rows(a, chunk_bytes), chunk_rows(b, chunk_bytes)
     if backend == "pallas":
         return block_diff_pallas(wa, wb, interpret=interpret)
     return block_diff_ref(wa, wb)
-
-
-_AUTO_BACKEND: list = []        # memoized working backend ([] = unprobed)
 
 
 def dirty_chunks(a: jax.Array, b: jax.Array,
                  chunk_bytes: int = 1 << 18) -> np.ndarray:
     """Indices of chunks where ``a`` and ``b`` differ bitwise, as a host
     int array — the exact-compare entry point the delta pipeline wires in
-    (Pallas kernel where it runs, jnp ref otherwise; memoized probe).
-    Raises when neither backend works (callers compare on host)."""
-    last_err: Exception = RuntimeError("no block_diff backend")
-    for backend in _AUTO_BACKEND or ("pallas", "ref"):
-        try:
-            mask = block_diff(a, b, chunk_bytes, backend=backend)
-        except Exception as e:  # noqa: BLE001 — backend unsupported here
-            last_err = e
-            continue
-        _AUTO_BACKEND[:] = [backend]
-        return np.nonzero(np.asarray(mask))[0]
-    raise last_err
+    (the Pallas kernel on a TPU, the jnp reference elsewhere)."""
+    mask = block_diff(a, b, chunk_bytes, backend=platform_backend(a))
+    return np.nonzero(np.asarray(mask))[0]

@@ -1,16 +1,22 @@
 """Pallas TPU kernel: per-chunk detection hash at HBM bandwidth.
 
-Grid: one program per chunk.  Each program streams one chunk of uint32 words
-HBM->VMEM (BlockSpec (1, W)), avalanche-mixes every word with its position
-(pure VPU ops: xor/mul/shift), XOR-tree-reduces, folds in the true byte
-length, and writes a (1, 2) uint32 hash pair.
+Grid: one program per chunk.  Each program streams one chunk HBM->VMEM as
+an (R, 128) tile of 32-bit words (``kernels/common.py``), avalanche-mixes
+every word with its position (pure VPU ops: xor/mul/shift), XOR-reduces
+the tile, folds in the true byte length, and writes the 2x32-bit hash pair
+into lanes 0 and 1 of a (1, 128) output row.
 
-The XOR reduction is an unrolled log2(W) halving tree — no sequential
-dependency, unlike FNV — which is exactly why this hash was chosen for the
-TPU adaptation (DESIGN.md §4).  W must be a power of two; ops.py pads.
+The XOR reduction halves the rows down to one (8, 128) vreg, then rotates
+and xors within it (``pltpu.roll``; xor is commutative, so the direction
+of the rotation does not matter) — no sequential dependency, unlike FNV,
+which is why this hash was chosen for the TPU (DESIGN.md §4).  Arithmetic
+is int32: multiplication and xor wrap exactly like uint32, and right
+shifts are logical, so the bits are those of ``hashing.chunk_hashes_np``.
 
-VMEM budget: one (1, W) uint32 block = 4*W bytes; the default W=65536
-(256 KiB chunks) uses 256 KiB of VMEM plus negligible intermediates.
+The chunk's true byte count comes in through scalar prefetch (SMEM, one
+word per chunk); ``MAX_CALL_CHUNKS`` bounds the chunks per call so it
+stays far below SMEM's 1 MiB.  VMEM: the double-buffered input tile (8 * W bytes) plus the
+mixing temporaries of one tile.
 """
 from __future__ import annotations
 
@@ -18,54 +24,93 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import C1, C2, GOLDEN, SEEDS
+from repro.kernels.common import (LANES, SUBLANES, TILE_WORDS, lane_pair,
+                                  tile_words)
+
+MAX_CALL_CHUNKS = 1 << 15        # SMEM words of nbytes per pallas_call
 
 
-def _xor_tree(v: jax.Array) -> jax.Array:
-    """XOR-reduce v [1, W] -> scalar via an unrolled halving tree."""
-    length = v.shape[1]
-    while length > 1:
-        half = length // 2
-        v = v[:, :half] ^ v[:, half:length]
-        length = half
-    return v[0, 0]
+def as_i32(c) -> int:
+    """A uint32 constant as the int32 with the same bits."""
+    return int(np.uint32(c).view(np.int32))
 
 
-def _chunk_hash_kernel(words_ref, nbytes_ref, out_ref):
-    w = words_ref[...]                                   # (1, W) uint32
-    wsize = w.shape[1]
-    idx = jax.lax.broadcasted_iota(jnp.uint32, (1, wsize), 1)
-    nbytes = nbytes_ref[0, 0].astype(jnp.uint32)
-    n_valid = (nbytes + 3) // 4          # padding words contribute zero
-    for lane, seed in enumerate(SEEDS):
-        m = (w ^ (idx * jnp.uint32(GOLDEN) + jnp.uint32(seed))) * jnp.uint32(C1)
-        m = m ^ (m >> 16)
-        m = m * jnp.uint32(C2)
-        m = m ^ (m >> 13)
-        m = jnp.where(idx < n_valid, m, jnp.uint32(0))
-        h = _xor_tree(m)
-        h = (h ^ nbytes) * jnp.uint32(C1)
-        h = h ^ (h >> 16)
-        out_ref[0, lane] = h
+def xor_all(v: jax.Array) -> jax.Array:
+    """XOR of every element of v [R, 128] (R a power of two >= 8), as a
+    (1, 128) vector whose lanes all hold it."""
+    rows = v.shape[0]
+    while rows > SUBLANES:
+        rows //= 2
+        v = v[:rows] ^ v[rows:2 * rows]
+    for s in (4, 2, 1):
+        v = v ^ pltpu.roll(v, s, 0)
+    for s in (64, 32, 16, 8, 4, 2, 1):
+        v = v ^ pltpu.roll(v, s, 1)
+    return v[0:1]
+
+
+def hash_tile(w: jax.Array, nbytes) -> tuple:
+    """Detection hash lanes (h0, h1) of one chunk tile w [R, 128] int32
+    holding ``nbytes`` valid bytes; each a (1, 128) int32 vector with all
+    lanes equal.  Words past the valid ones (zero padding) contribute 0."""
+    rows = w.shape[0]
+    idx = (jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1))
+    n_valid = (nbytes + 3) // 4
+    srl = jax.lax.shift_right_logical
+    out = []
+    for seed in SEEDS:
+        m = (w ^ (idx * as_i32(GOLDEN) + as_i32(seed))) * as_i32(C1)
+        m = m ^ srl(m, 16)
+        m = m * as_i32(C2)
+        m = m ^ srl(m, 13)
+        m = jnp.where(idx < n_valid, m, 0)
+        h = (xor_all(m) ^ nbytes) * as_i32(C1)
+        out.append(h ^ srl(h, 16))
+    return out[0], out[1]
+
+
+def _chunk_hash_kernel(nbytes_ref, words_ref, out_ref):
+    h0, h1 = hash_tile(words_ref[0], nbytes_ref[pl.program_id(0)])
+    out_ref[0] = lane_pair(h0, h1)
+
+
+def _hash_call(tiles: jax.Array, nbytes: jax.Array, interpret: bool):
+    n, rows, _ = tiles.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n,),
+        in_specs=[pl.BlockSpec((1, rows, LANES), lambda i, nb: (i, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, LANES), lambda i, nb: (i, 0, 0)),
+    )
+    out = pl.pallas_call(
+        _chunk_hash_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, 1, LANES), jnp.int32),
+        interpret=interpret,
+    )(nbytes, tiles)
+    return out[:, 0, :2]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def chunk_hash_pallas(words: jax.Array, nbytes: jax.Array, *,
                       interpret: bool = False) -> jax.Array:
-    """words: uint32 [n_chunks, W] (W power of two); nbytes: int32 [n_chunks].
-    Returns uint32 [n_chunks, 2]."""
-    n_chunks, wsize = words.shape
-    assert wsize & (wsize - 1) == 0, f"W={wsize} must be a power of two"
-    return pl.pallas_call(
-        _chunk_hash_kernel,
-        grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, wsize), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, 2), jnp.uint32),
-        interpret=interpret,
-    )(words, nbytes.reshape(-1, 1))
+    """words: uint32 [n_chunks, W] (W a power of two) or its word tiles
+    [n_chunks, R, 128]; nbytes: int32 [n_chunks].  Returns uint32
+    [n_chunks, 2]."""
+    if words.ndim == 2:
+        wsize = words.shape[1]
+        assert wsize & (wsize - 1) == 0, f"W={wsize} must be a power of two"
+        words = tile_words(words, TILE_WORDS)
+    tiles = jax.lax.bitcast_convert_type(words, jnp.int32)
+    nbytes = nbytes.astype(jnp.int32)
+    parts = [_hash_call(tiles[s:s + MAX_CALL_CHUNKS],
+                        nbytes[s:s + MAX_CALL_CHUNKS], interpret)
+             for s in range(0, tiles.shape[0], MAX_CALL_CHUNKS)]
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)

@@ -1,28 +1,31 @@
 """Pallas TPU kernel: on-device bit-plane encode of the compacted buffer.
 
-Runs immediately after ``delta_pack`` on the same device, turning the
-compacted dirty-chunk buffer into the codec's plane stream *before* it
-crosses PCIe — the host then assembles KZC1 frames (``host.py``) without
-ever seeing the raw bytes.
+Runs right after ``delta_pack`` on the same device, turning the compacted
+dirty-chunk buffer into the codec's plane stream *before* it crosses PCIe
+— the host then assembles KZC1 frames (``host.py``) without ever seeing
+the raw bytes.
 
-Grid: one program per group (``gw`` words), sequential per core, so the
-SMEM running counter is a legal cross-step accumulator — the same
-compaction pattern as ``delta_pack``.  Each step streams one (1, gw) block
-in, classifies its 32 bit-planes (all-zero / all-one / stored) with
-unrolled OR/AND halving trees (no axis reductions — Mosaic-friendly), packs
-stored planes into gw-bit bitmaps via a shift + OR-tree, and appends them
-at the running position.
+Layout: the wrapper transposes each group of ``gw`` words so that the 32
+words one plane word is built from lie along sublanes: block g is the
+(32, gw/32) tile ``t[k, j] = word j*32 + k``.  Plane p's bitmap word j is
+then ``OR_k ((t[k, j] >> p) & 1) << k`` — a reduction over rows (halving
+to one vreg of rows, then rotate-and-or, whose result does not depend on
+the rotation's direction), with no reshape to 32 lanes.
 
-Outputs (group-major, plane-ascending — byte-identical stream to
-``host.plane_split`` + compaction):
+Grid: one program per group.  Each step writes its 32 bitmaps (the
+bitshuffled group, same size as the input) and the group's
+(stored_mask, ones_mask) pair into lanes 0/1 of a (1, 128) row.  A plane
+is all-zero when no bit of it is set and all-one when every bit is; both
+are scalar reductions of the plane's bits.  Compaction of the stored
+planes to the front of the stream happens after the kernel, in XLA
+(``ref.compact_planes``), so the kernel has no data-dependent stores.
+
+Outputs (same contract as :func:`ref.codec_encode_ref`; the plane stream
+is byte-identical to ``host.plane_split`` + compaction):
   masks   uint32 [n_groups, 2]        — (stored_mask, ones_mask)
   count   int32  [1, 1]               — total stored planes
   planes  uint32 [n_groups*32, gw/32] — stored planes compacted to the
                                         front; rows past ``count`` garbage
-
-VMEM: one (1, gw) input block plus the whole planes buffer
-(n_groups * 32 * gw/8 bytes = input_bytes) — callers reuse delta_pack's
-segment bound, so a call never exceeds the segment budget.
 """
 from __future__ import annotations
 
@@ -33,59 +36,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import LANES, SUBLANES, lane_pair
+from repro.kernels.delta_codec.ref import compact_planes
 
-def _or_tree_rows(v: jax.Array) -> jax.Array:
-    """OR-reduce v [rows, 1] -> scalar via an unrolled halving tree."""
+PLANES = 32
+
+
+def _or_rows(v: jax.Array) -> jax.Array:
+    """OR of the 32 rows of v [32, pw] -> (1, pw)."""
     rows = v.shape[0]
-    while rows > 1:
-        half = rows // 2
-        v = v[:half, :] | v[half:rows, :]
-        rows = half
-    return v[0, 0]
+    while rows > SUBLANES:
+        rows //= 2
+        v = v[:rows] | v[rows:2 * rows]
+    for s in (4, 2, 1):
+        v = v | pltpu.roll(v, s, 0)
+    return v[0:1]
 
 
-def _codec_encode_kernel(words_ref, masks_ref, count_ref, planes_ref,
-                         cnt_ref):
-    g = pl.program_id(0)
-
-    @pl.when(g == 0)
-    def _():
-        cnt_ref[0] = 0                 # running stored-plane counter
-
-    w = words_ref[...]                                   # (1, gw) uint32
-    gw = w.shape[1]
-    pw = gw // 32
-    grouped = w.reshape(pw, 32)        # element [j, k] = word j*32 + k
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (pw, 32), 1)
-    base = cnt_ref[0]
-    off = jnp.int32(0)
-    smask = jnp.uint32(0)
-    omask = jnp.uint32(0)
-    for p in range(32):                # unrolled: 32 static plane slots
-        bits = (grouped >> jnp.uint32(p)) & jnp.uint32(1)
-        v = bits << shifts             # (pw, 32): lane k carries bit k
-        length = 32
-        while length > 1:              # OR-tree pack -> bitmap word per row
-            half = length // 2
-            v = v[:, :half] | v[:, half:length]
-            length = half
-        packed = v                     # (pw, 1): plane p's gw-bit bitmap
-        zero = _or_tree_rows(packed) == jnp.uint32(0)
-        ones = _or_tree_rows(~packed) == jnp.uint32(0)
-        store = jnp.logical_not(zero) & jnp.logical_not(ones)
-        smask = smask | (store.astype(jnp.uint32) << jnp.uint32(p))
-        omask = omask | (ones.astype(jnp.uint32) << jnp.uint32(p))
-
-        @pl.when(store)
-        def _(packed=packed, off=off):
-            planes_ref[pl.ds(base + off, 1), :] = packed.reshape(1, pw)
-
-        off = off + store.astype(jnp.int32)
-
-    masks_ref[0, 0] = smask
-    masks_ref[0, 1] = omask
-    cnt_ref[0] = base + off
-    count_ref[0, 0] = base + off       # last program leaves the total
+def _codec_encode_kernel(t_ref, planes_ref, masks_ref):
+    t = t_ref[0]                       # (32, pw) int32; row k = bit lane k
+    k = jax.lax.broadcasted_iota(jnp.int32, t.shape, 0)
+    smask = jnp.int32(0)
+    omask = jnp.int32(0)
+    for p in range(PLANES):            # unrolled: 32 static plane slots
+        bits = jax.lax.shift_right_logical(t, p) & 1
+        planes_ref[0, p:p + 1, :] = _or_rows(bits << k)
+        some = jnp.max(bits)           # 0: all-zero plane
+        every = jnp.min(bits)          # 1: all-one plane
+        smask = smask | ((some & (1 - every)) << p)
+        omask = omask | (every << p)
+    masks_ref[0] = lane_pair(smask, omask)
 
 
 @functools.partial(jax.jit, static_argnames=("gw", "interpret"))
@@ -99,25 +79,21 @@ def codec_encode_pallas(rows: jax.Array, *, gw: int,
     r, w = rows.shape
     assert gw >= 32 and gw & (gw - 1) == 0, f"gw={gw}"
     assert w % gw == 0, (w, gw)
-    gpr = w // gw
-    ng = r * gpr
-    pw = gw // 32
-    return pl.pallas_call(
+    ng = r * (w // gw)
+    pw = gw // PLANES
+    t = rows.reshape(ng, pw, PLANES).swapaxes(1, 2)
+    planes, masks = pl.pallas_call(
         _codec_encode_kernel,
         grid=(ng,),
-        in_specs=[
-            pl.BlockSpec((1, gw), lambda g: (g // gpr, g % gpr)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 2), lambda g: (g, 0)),
-            pl.BlockSpec((1, 1), lambda g: (0, 0)),
-            pl.BlockSpec((ng * 32, pw), lambda g: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((ng, 2), jnp.uint32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((ng * 32, pw), jnp.uint32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        in_specs=[pl.BlockSpec((1, PLANES, pw), lambda g: (g, 0, 0))],
+        out_specs=[pl.BlockSpec((1, PLANES, pw), lambda g: (g, 0, 0)),
+                   pl.BlockSpec((1, 1, LANES), lambda g: (g, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((ng, PLANES, pw), jnp.int32),
+                   jax.ShapeDtypeStruct((ng, 1, LANES), jnp.int32)],
         interpret=interpret,
-    )(rows)
+    )(jax.lax.bitcast_convert_type(t, jnp.int32))
+    u32 = functools.partial(jax.lax.bitcast_convert_type,
+                            new_dtype=jnp.uint32)
+    masks = u32(masks[:, 0, :2])
+    count, buf = compact_planes(u32(planes), masks[:, 0])
+    return masks, count, buf

@@ -13,13 +13,12 @@ stays O(log max_rows) per (W, gw).
 from __future__ import annotations
 
 import os
-from typing import List, Tuple
+from typing import Optional
 
 import numpy as np
 
 from repro.kernels.delta_codec import host
 
-_AUTO_BACKEND: List[str] = []          # memoized first working backend
 _MIN_ROW_PAD = 8
 
 
@@ -35,16 +34,21 @@ def group_words_for(width: int) -> int:
     return min(host.GROUP_WORDS, width)
 
 
-def encode_rows(rows, *, backend: str = "pallas", interpret: bool = False):
+def encode_rows(rows, *, width: Optional[int] = None,
+                backend: str = "pallas", interpret: bool = False):
     """Encode uint32 device ``rows`` [R, W] (R >= 1, W a power of two >=
-    MIN_GROUP_WORDS).
+    MIN_GROUP_WORDS); rows of another shape (such as ``delta_pack``'s word
+    tiles [R, ..]) are read as their first ``width`` words each.
 
     Returns (masks np.uint32 [R*gpr, 2], planes_dev [n_stored, gw//32]
     still on device, gw).  Only the masks (8 bytes/group) are materialized
     here; the caller transfers ``planes_dev`` when it is ready for it."""
     import jax.numpy as jnp
 
-    r, w = int(rows.shape[0]), int(rows.shape[1])
+    r = int(rows.shape[0])
+    w = int(width or rows.shape[1])
+    if rows.ndim != 2 or rows.shape[1] != w:
+        rows = rows.reshape(r, -1)[:, :w]
     gw = group_words_for(w)
     if gw < host.MIN_GROUP_WORDS or w % gw:
         raise ValueError(f"row width {w} not codec-eligible")
@@ -66,31 +70,16 @@ def encode_rows(rows, *, backend: str = "pallas", interpret: bool = False):
     return masks, planes_d[:n_stored], gw
 
 
-def encode_rows_auto(rows):
-    """encode_rows with the memoized pallas -> jnp-ref fallback ladder
-    (same probe pattern as delta_pack / chunk_hash)."""
-    if _AUTO_BACKEND:
-        return encode_rows(rows, backend=_AUTO_BACKEND[0])
-    last: Exception = RuntimeError("no codec backend")
-    for backend in ("pallas", "ref"):
-        try:
-            out = encode_rows(rows, backend=backend)
-            _AUTO_BACKEND.append(backend)
-            return out
-        except Exception as e:  # noqa: BLE001 — probe failures expected
-            last = e
-    raise last
-
-
 def probe_device_rows(rows, max_rows: int = 4,
                       sample_words: int = 256) -> bool:
     """Device-side analogue of ``host.bitplane_probe``: pull a small word
     sample from the compacted buffer (a few hundred bytes over PCIe) and
     estimate whether the encode is worth launching at all."""
-    r, w = int(rows.shape[0]), int(rows.shape[1])
+    r = int(rows.shape[0])
     if r == 0:
         return False
     take = min(r, max_rows)
+    w = int(np.prod(rows.shape[1:]))
     step = max(1, (take * w) // sample_words)
-    sample = np.asarray(rows[:take]).reshape(-1)[::step][:sample_words]
+    sample = np.asarray(rows[:take].reshape(-1)[::step][:sample_words])
     return host.estimate_stored_fraction(sample) < host.PROBE_THRESHOLD

@@ -1,9 +1,9 @@
 """jnp reference encoder for the bit-plane codec.
 
 Same contract as :func:`kernel.codec_encode_pallas` and the same plane
-stream as ``host.bitplane_compress`` — the compaction order (stable sort on
-the negated store flags) matches the kernel's running-counter append order,
-so device payloads are byte-identical to host payloads.
+stream as ``host.bitplane_compress``: stored planes are compacted to the
+front in (group, plane) order (:func:`compact_planes`, which the kernel's
+wrapper shares), so device payloads are byte-identical to host payloads.
 """
 from __future__ import annotations
 
@@ -11,6 +11,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+
+def compact_planes(planes: jax.Array, stored_mask: jax.Array):
+    """planes uint32 [ng, 32, pw]; stored_mask uint32 [ng] -> (count
+    int32 [1, 1], planes [ng*32, pw] with the stored ones first in
+    (group, plane) order)."""
+    ng, n_planes, pw = planes.shape
+    shifts = jnp.arange(n_planes, dtype=jnp.uint32)
+    flags = ((stored_mask[:, None] >> shifts) & 1).astype(bool).reshape(-1)
+    order = jnp.argsort(~flags, stable=True)                 # stored first
+    count = jnp.sum(flags.astype(jnp.int32)).reshape(1, 1)
+    return count, planes.reshape(ng * n_planes, pw)[order]
 
 
 @functools.partial(jax.jit, static_argnames=("gw",))
@@ -41,9 +53,5 @@ def codec_encode_ref(rows: jax.Array, *, gw: int):
                     axis=1, dtype=jnp.uint32)
     omask = jnp.sum(jnp.where(ones, jnp.uint32(1) << shifts, 0),
                     axis=1, dtype=jnp.uint32)
-    masks = jnp.stack([smask, omask], axis=1)
-    flags = store.reshape(ng * 32)
-    order = jnp.argsort(~flags, stable=True)                 # stored first
-    buf = planes.reshape(ng * 32, pw)[order]
-    count = jnp.sum(flags.astype(jnp.int32)).reshape(1, 1)
-    return masks, count, buf
+    count, buf = compact_planes(planes, smask)
+    return jnp.stack([smask, omask], axis=1), count, buf

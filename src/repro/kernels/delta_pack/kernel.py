@@ -3,34 +3,32 @@
 One pass over HBM per chunk does everything the checkpoint writer's
 detection+extraction hot path needs:
 
-  hash      — avalanche-mix + XOR-tree-reduce each (1, W) uint32 block into
-              the 2x32-bit detection hash pair (same math as ``chunk_hash``;
-              the spec lives in repro.core.hashing)
-  diff      — compare the pair against the *previous* commit's hash pair for
-              that chunk (prefetched alongside the data block)
-  compact   — dirty chunks are appended, in chunk order, to a compacted
-              output buffer at a running-counter position, so the caller
-              transfers ``count`` rows device→host instead of the whole array
+  hash      — avalanche-mix + XOR-reduce each chunk tile into the 2x32-bit
+              detection hash pair (``chunk_hash.kernel.hash_tile``; the
+              spec lives in repro.core.hashing)
+  diff      — compare the pair against the *previous* commit's pair for
+              that chunk (scalar-prefetched into SMEM)
+  compact   — dirty chunks are copied, in chunk order, to a compacted
+              buffer at a running-counter row, so the caller transfers
+              ``count`` rows device→host instead of the whole array
 
 Grid: one program per chunk, executed sequentially per core (the TPU grid
 contract), which makes the SMEM running counter a legal cross-step
-accumulator — the standard Pallas compaction pattern.  Streams one (1, W)
-block in, writes the (1, 2) hash pair, a dirty flag, the chunk's compacted
-position (-1 when clean), and conditionally one (1, W) row of the compacted
-buffer: bandwidth-bound at ~1 read stream + dirty-fraction write stream.
+accumulator.  Each step streams one (R, 128) word tile in, writes the hash
+pair into lanes 0/1 of a (1, 128) row and, when the chunk is dirty, DMAs
+the tile (already in VMEM from the hash read — no second HBM fetch) to its
+row of the compacted buffer, which stays in HBM.  So VMEM holds only the
+double-buffered input tile, whatever the segment size.
 
-Outputs (in order):
+The kernel computes in int32 (bit-identical to uint32 for this hash).
+``delta_pack_pallas`` returns the same five outputs as ``ref.delta_pack_ref``:
   hashes  uint32 [n_chunks, 2]   — detection hash pairs (lane 0 = high word)
   dirty   int32  [n_chunks, 1]   — 1 iff the pair differs from ``prev``
   pos     int32  [n_chunks, 1]   — row of the chunk in the compacted buffer,
                                    -1 when clean
   count   int32  [1, 1]          — total dirty chunks (valid rows of ``buf``)
-  buf     uint32 [n_chunks, W]   — compacted dirty chunks; rows past
+  buf     uint32 [n_chunks, R, 128] — compacted dirty chunk tiles; rows past
                                    ``count`` are unwritten garbage
-
-VMEM budget: the input block plus the *whole* compacted buffer are resident
-(4*W + 4*n_chunks*W bytes) — ops.py bounds n_chunks per call by segmenting,
-so a call never exceeds its VMEM budget regardless of array size.
 """
 from __future__ import annotations
 
@@ -41,22 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hashing import C1, C2, GOLDEN, SEEDS
+from repro.kernels.chunk_hash.kernel import hash_tile
+from repro.kernels.common import LANES, TILE_WORDS, lane_pair, tile_words
 
 
-def _xor_tree(v: jax.Array) -> jax.Array:
-    """XOR-reduce v [1, W] -> scalar via an unrolled halving tree."""
-    length = v.shape[1]
-    while length > 1:
-        half = length // 2
-        v = v[:, :half] ^ v[:, half:length]
-        length = half
-    return v[0, 0]
-
-
-def _delta_pack_kernel(words_ref, prev_ref, nbytes_ref,
-                       hash_ref, dirty_ref, pos_ref, count_ref, buf_ref,
-                       cnt_ref):
+def _delta_pack_kernel(nbytes_ref, prev_ref, words_ref, hash_ref, buf_ref,
+                       cnt_ref, sem):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -64,73 +52,60 @@ def _delta_pack_kernel(words_ref, prev_ref, nbytes_ref,
         cnt_ref[0] = 0                 # running compaction counter (SMEM
                                        # scratch persists across grid steps)
 
-    w = words_ref[...]                                   # (1, W) uint32
-    wsize = w.shape[1]
-    idx = jax.lax.broadcasted_iota(jnp.uint32, (1, wsize), 1)
-    nbytes = nbytes_ref[0, 0].astype(jnp.uint32)
-    n_valid = (nbytes + 3) // 4          # padding words contribute zero
-    lanes = []
-    for lane, seed in enumerate(SEEDS):
-        m = (w ^ (idx * jnp.uint32(GOLDEN) + jnp.uint32(seed))) * jnp.uint32(C1)
-        m = m ^ (m >> 16)
-        m = m * jnp.uint32(C2)
-        m = m ^ (m >> 13)
-        m = jnp.where(idx < n_valid, m, jnp.uint32(0))
-        h = _xor_tree(m)
-        h = (h ^ nbytes) * jnp.uint32(C1)
-        h = h ^ (h >> 16)
-        hash_ref[0, lane] = h
-        lanes.append(h)
-
-    dirty = (lanes[0] != prev_ref[0, 0]) | (lanes[1] != prev_ref[0, 1])
-    d32 = dirty.astype(jnp.int32)
-    dirty_ref[0, 0] = d32
+    h0, h1 = hash_tile(words_ref[0], nbytes_ref[i])
+    hash_ref[0] = lane_pair(h0, h1)
+    ne = (h0 != prev_ref[2 * i]) | (h1 != prev_ref[2 * i + 1])
+    dirty = jnp.max(ne.astype(jnp.int32))
     pos = cnt_ref[0]
-    pos_ref[0, 0] = jnp.where(dirty, pos, -1)
 
-    @pl.when(dirty)
+    @pl.when(dirty > 0)
     def _():
-        # append this chunk's words at the next free compacted row; the
-        # block is already in VMEM from the hash read — no second HBM fetch
-        buf_ref[pl.ds(pos, 1), :] = w
+        copy = pltpu.make_async_copy(words_ref, buf_ref.at[pl.ds(pos, 1)],
+                                     sem)
+        copy.start()
+        copy.wait()
 
-    cnt_ref[0] = pos + d32
-    count_ref[0, 0] = pos + d32        # last program leaves the total
+    cnt_ref[0] = pos + dirty
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def delta_pack_pallas(words: jax.Array, prev: jax.Array, nbytes: jax.Array,
                       *, interpret: bool = False):
-    """words: uint32 [n_chunks, W] (W power of two); prev: uint32
-    [n_chunks, 2] previous hash pairs; nbytes: int32 [n_chunks].
+    """words: uint32 [n_chunks, W] (W a power of two) or its word tiles
+    [n_chunks, R, 128] (R >= 8); prev: uint32 [n_chunks, 2] previous hash
+    pairs; nbytes: int32 [n_chunks].
 
     Returns (hashes [n,2] u32, dirty [n,1] i32, pos [n,1] i32,
-    count [1,1] i32, buf [n,W] u32)."""
-    n_chunks, wsize = words.shape
-    assert wsize & (wsize - 1) == 0, f"W={wsize} must be a power of two"
+    count [1,1] i32, buf [n,R,128] u32)."""
+    if words.ndim == 2:
+        wsize = words.shape[1]
+        assert wsize & (wsize - 1) == 0, f"W={wsize} must be a power of two"
+        words = tile_words(words, TILE_WORDS)
+    n_chunks, rows, _ = words.shape
     assert prev.shape == (n_chunks, 2), (prev.shape, n_chunks)
-    return pl.pallas_call(
-        _delta_pack_kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, wsize), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n_chunks, wsize), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_chunks, 2), jnp.uint32),
-            jax.ShapeDtypeStruct((n_chunks, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_chunks, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_chunks, wsize), jnp.uint32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        in_specs=[pl.BlockSpec((1, rows, LANES),
+                               lambda i, nb, pv: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((1, 1, LANES), lambda i, nb, pv: (i, 0, 0)),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    i32 = functools.partial(jax.lax.bitcast_convert_type, new_dtype=jnp.int32)
+    hash_rows, buf = pl.pallas_call(
+        _delta_pack_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((n_chunks, 1, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((n_chunks, rows, LANES), jnp.int32)],
         interpret=interpret,
-    )(words, prev, nbytes.reshape(-1, 1))
+    )(nbytes.astype(jnp.int32), i32(prev).reshape(-1), i32(words))
+    u32 = functools.partial(jax.lax.bitcast_convert_type,
+                            new_dtype=jnp.uint32)
+    hashes = u32(hash_rows[:, 0, :2])
+    dirty = jnp.any(hashes != prev, axis=1)
+    d32 = dirty.astype(jnp.int32)
+    cum = jnp.cumsum(d32)
+    pos = jnp.where(dirty, cum - 1, -1).astype(jnp.int32)
+    return hashes, d32[:, None], pos[:, None], cum[-1:][:, None], u32(buf)

@@ -9,10 +9,12 @@ writer then streams only the dirty rows host-side via
 segment *i+1* is issued before segment *i*'s rows are consumed) so the
 device→host DMA overlaps the backend ``put_chunks`` upload.
 
-VMEM bounding: the kernel keeps its whole compacted output in VMEM, so the
-wrapper segments the array into super-blocks of at most ``seg_bytes``
-(default 4 MiB) chunks and launches one ``pallas_call`` per segment — at
-most two jit shapes (full segments + the tail) regardless of array size.
+Segments: the wrapper cuts the array into super-blocks of at most
+``seg_bytes`` (default 4 MiB) of chunks and launches one ``pallas_call``
+per segment — at most two jit shapes (full segments + the tail) whatever
+the array size, a bounded SMEM operand of previous hashes per call, and a
+unit of double-buffered device→host transfer.  The kernel keeps the
+compacted rows in HBM, so VMEM use does not grow with the segment.
 
 Traffic accounting: ``bytes_transferred`` counts every byte this pack moved
 device→host — 12 bytes/chunk of metadata (8 hash + 4 dirty flag) plus the
@@ -21,14 +23,19 @@ roofline in benchmarks/bench_device_delta.py.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Iterable, Iterator, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hashing
+from repro.kernels.chunk_hash.ops import chunk_nbytes, chunk_rows
+from repro.kernels.common import TILE_WORDS, platform_backend, tile_words
 
-DEFAULT_SEG_BYTES = 4 << 20      # compacted VMEM buffer bound per launch
+DEFAULT_SEG_BYTES = 4 << 20      # bytes of chunks per kernel launch
 
 
 def _obs_span(name: str, **args):
@@ -49,7 +56,8 @@ class _Seg:
     start: int                   # first chunk index covered by this segment
     stop: int
     dirty: np.ndarray            # global indices of dirty chunks, ascending
-    buf: Any                     # device uint32 [len(dirty), W] compacted rows
+    buf: Any                     # device uint32 [len(dirty), ...] compacted
+                                 # rows (word tiles on the kernel path)
 
 
 @dataclass
@@ -125,7 +133,7 @@ class DeltaPack:
             host = np.asarray(seg.buf)          # blocks on this segment only
             self.bytes_transferred += host.nbytes
             rowmap = {int(ci): r for r, ci in enumerate(seg.dirty)}
-            raw = host.view(np.uint8)
+            raw = host.reshape(host.shape[0], -1).view(np.uint8)
             for ci in sel:
                 row = raw[rowmap[ci]]
                 yield ci, row[: self._chunk_len(ci)].tobytes()
@@ -154,12 +162,8 @@ class DeltaPack:
             return
         width = self.chunk_bytes // 4
         engage = (codec_ops.device_codec_enabled()
-                  and width >= codec_host.MIN_GROUP_WORDS)
-        if engage:                      # sampled-incompressibility probe
-            try:
-                engage = codec_ops.probe_device_rows(plan[0][0].buf)
-            except Exception:  # noqa: BLE001 — probe trouble: go raw
-                engage = False
+                  and width >= codec_host.MIN_GROUP_WORDS
+                  and codec_ops.probe_device_rows(plan[0][0].buf))
         if not engage:
             self.codec_chunks_skipped += sum(len(sel) for _, sel in plan)
             for ci, data in self.read_chunks(indices):
@@ -167,34 +171,23 @@ class DeltaPack:
             return
 
         # phase 1: launch every segment's encode, overlap plane DMA
-        enc: List[Optional[tuple]] = []
+        from repro.core.delta import note_kernel_call
+
+        backend = platform_backend(plan[0][0].buf)
+        enc: List[tuple] = []
         for seg, _sel in plan:
+            note_kernel_call("delta_codec", backend)
+            with _obs_span("encode_dev", rows=int(seg.dirty.size)):
+                masks, planes_dev, gw = codec_ops.encode_rows(
+                    seg.buf, width=width, backend=backend)
             try:
-                with _obs_span("encode_dev", rows=int(seg.dirty.size)):
-                    masks, planes_dev, gw = codec_ops.encode_rows_auto(
-                        seg.buf)
-                try:
-                    planes_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
-                enc.append((masks, planes_dev, gw))
-            except Exception as e:  # noqa: BLE001 — encode degrades to raw
-                from repro.core.delta import note_kernel_fallback
-                note_kernel_fallback("codec_encode", e)
-                enc.append(None)
+                planes_dev.copy_to_host_async()
+            except AttributeError:
+                pass
+            enc.append((masks, planes_dev, gw))
 
         # phase 2: materialize plane streams, assemble per-chunk frames
         for k, (seg, sel) in enumerate(plan):
-            if enc[k] is None:          # this segment degraded to raw
-                host = np.asarray(seg.buf)
-                self.bytes_transferred += host.nbytes
-                self.codec_chunks_skipped += len(sel)
-                rowmap = {int(ci): r for r, ci in enumerate(seg.dirty)}
-                raw = host.view(np.uint8)
-                for ci in sel:
-                    row = raw[rowmap[ci]]
-                    yield ci, row[: self._chunk_len(ci)].tobytes(), None
-                continue
             masks, planes_dev, gw = enc[k]
             planes = np.asarray(planes_dev)     # blocks on this DMA only
             self.bytes_transferred += masks.nbytes + planes.nbytes
@@ -215,6 +208,13 @@ class DeltaPack:
                     yield ci, logical, None
 
 
+@functools.partial(jax.jit, static_argnames=("chunk_bytes", "tiled"))
+def _pack_words(x, chunk_bytes: int, tiled: bool):
+    """Word rows [n_chunks, W] of ``x``, as (R, 128) tiles for the kernel."""
+    rows = chunk_rows(x, chunk_bytes)
+    return tile_words(rows, TILE_WORDS) if tiled else rows
+
+
 def delta_pack(x, prev_hashes, chunk_bytes: int = 1 << 18, *,
                backend: str = "pallas", interpret: bool = False,
                seg_bytes: int = DEFAULT_SEG_BYTES) -> DeltaPack:
@@ -224,10 +224,8 @@ def delta_pack(x, prev_hashes, chunk_bytes: int = 1 << 18, *,
     ``prev_hashes`` is uint64 [n_chunks] (the previous LeafRecord's
     ``base_hashes``); ``chunk_bytes`` must be a power-of-two multiple of 4.
     The returned hashes are bit-identical to ``hashing.chunk_hashes_np``.
+    ``backend`` is "pallas" (the TPU kernel) or "ref" (the jnp reference).
     """
-    import jax.numpy as jnp
-
-    from repro.kernels.chunk_hash.ops import _to_words
     from repro.kernels.delta_pack.kernel import delta_pack_pallas
     from repro.kernels.delta_pack.ref import delta_pack_ref
 
@@ -237,21 +235,16 @@ def delta_pack(x, prev_hashes, chunk_bytes: int = 1 << 18, *,
         return DeltaPack(nbytes=0, chunk_bytes=chunk_bytes, n_chunks=0,
                          hashes=np.zeros((0,), np.uint64),
                          dirty=np.zeros((0,), np.int64))
-    wpc = chunk_bytes // 4
     n_chunks = -(-nbytes_total // chunk_bytes)
     prev = np.asarray(prev_hashes, dtype=np.uint64).reshape(-1)
     assert prev.shape == (n_chunks,), (prev.shape, n_chunks)
-    words = _to_words(x)
-    pad = n_chunks * wpc - words.shape[0]
-    if pad:
-        words = jnp.concatenate([words, jnp.zeros((pad,), jnp.uint32)])
-    words = words.reshape(n_chunks, wpc)
+    words = _pack_words(x, chunk_bytes, backend == "pallas")
     prev32 = jnp.asarray(hashing.split_u64(prev))
-    nb_np = np.minimum(
-        np.full(n_chunks, chunk_bytes, np.int64),
-        np.maximum(nbytes_total
-                   - np.arange(n_chunks, dtype=np.int64) * chunk_bytes, 0)
-    ).astype(np.int32)
+    nb_np = chunk_nbytes(nbytes_total, chunk_bytes)
+    if backend == "pallas":
+        fn = functools.partial(delta_pack_pallas, interpret=interpret)
+    else:
+        fn = delta_pack_ref
 
     seg_chunks = max(1, seg_bytes // chunk_bytes)
     segs: List[_Seg] = []
@@ -260,10 +253,8 @@ def delta_pack(x, prev_hashes, chunk_bytes: int = 1 << 18, *,
     moved = 0
     for s0 in range(0, n_chunks, seg_chunks):
         s1 = min(s0 + seg_chunks, n_chunks)
-        fn = delta_pack_pallas if backend == "pallas" else delta_pack_ref
-        kw = {"interpret": interpret} if backend == "pallas" else {}
         h, d, _pos, cnt, buf = fn(words[s0:s1], prev32[s0:s1],
-                                  jnp.asarray(nb_np[s0:s1]), **kw)
+                                  jnp.asarray(nb_np[s0:s1]))
         count = int(np.asarray(cnt)[0, 0])
         dflags = np.asarray(d).reshape(-1)
         hash_parts.append(np.asarray(h))
@@ -280,25 +271,3 @@ def delta_pack(x, prev_hashes, chunk_bytes: int = 1 << 18, *,
     return DeltaPack(nbytes=nbytes_total, chunk_bytes=chunk_bytes,
                      n_chunks=n_chunks, hashes=hashes, dirty=dirty,
                      bytes_transferred=moved, _segments=segs)
-
-
-_AUTO_BACKEND: list = []        # memoized working backend ([] = unprobed)
-
-
-def delta_pack_auto(x, prev_hashes, chunk_bytes: int = 1 << 18,
-                    **kw) -> DeltaPack:
-    """DeltaPack with backend auto-selection: the Pallas kernel where it
-    runs (TPU), the jnp reference otherwise; raises only when neither works
-    (callers then take the host path).  Probed once and memoized, like
-    ``chunk_hash_u64_auto`` — this runs per leaf per commit."""
-    last_err: Exception = RuntimeError("no delta_pack backend")
-    for backend in _AUTO_BACKEND or ("pallas", "ref"):
-        try:
-            pack = delta_pack(x, prev_hashes, chunk_bytes,
-                              backend=backend, **kw)
-        except Exception as e:  # noqa: BLE001 — backend unsupported here
-            last_err = e
-            continue
-        _AUTO_BACKEND[:] = [backend]
-        return pack
-    raise last_err

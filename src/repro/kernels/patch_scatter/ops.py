@@ -13,13 +13,11 @@ O(log max_rows) per (C, W).
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import numpy as np
 
 from repro.kernels.delta_codec.host import pow2ceil
-
-_AUTO_BACKEND: List[str] = []          # memoized first working backend
 
 
 def _from_words(words, dtype, shape):
@@ -79,24 +77,19 @@ def scatter_chunks(x, idx: Sequence[int], blobs: Sequence[bytes],
     fused pass.
 
     Returns (patched array, bytes moved host->device).  Raises on any
-    contract violation — callers fall back to the per-chunk
-    ``dynamic_update_slice`` ladder."""
+    contract violation; ``delta.patch_device_chunks`` checks the contract
+    before it calls."""
     import jax.numpy as jnp
 
-    from repro.kernels.chunk_hash.ops import _to_words
+    from repro.kernels.chunk_hash.ops import chunk_rows
 
     if chunk_bytes <= 0 or chunk_bytes % 4:
         raise ValueError(f"chunk_bytes {chunk_bytes} not word-aligned")
     if not blobs:
         return x, 0
     width = chunk_bytes // 4
-    nbytes = x.size * np.dtype(x.dtype).itemsize
-    n_chunks = max(-(-int(nbytes) // chunk_bytes), 1)
-    words = _to_words(x)
-    pad = n_chunks * width - words.shape[0]
-    if pad:
-        words = jnp.concatenate([words, jnp.zeros((pad,), jnp.uint32)])
-    words = words.reshape(n_chunks, width)
+    words = chunk_rows(x, chunk_bytes)
+    n_chunks = words.shape[0]
 
     k = len(blobs)
     idx_np = np.asarray(idx, np.int32)
@@ -123,20 +116,3 @@ def scatter_chunks(x, idx: Sequence[int], blobs: Sequence[bytes],
     else:
         raise ValueError(f"unknown scatter backend {backend!r}")
     return _from_words(out.reshape(-1), x.dtype, x.shape), moved
-
-
-def scatter_chunks_auto(x, idx, blobs, chunk_bytes: int):
-    """scatter_chunks with the memoized pallas -> jnp-ref fallback ladder."""
-    if _AUTO_BACKEND:
-        return scatter_chunks(x, idx, blobs, chunk_bytes,
-                              backend=_AUTO_BACKEND[0])
-    last: Exception = RuntimeError("no scatter backend")
-    for backend in ("pallas", "ref"):
-        try:
-            out = scatter_chunks(x, idx, blobs, chunk_bytes,
-                                 backend=backend)
-            _AUTO_BACKEND.append(backend)
-            return out
-        except Exception as e:  # noqa: BLE001 — probe failures expected
-            last = e
-    raise last

@@ -373,6 +373,9 @@ def cmd_kishud(store_uri: str, args) -> int:
     from repro.launch import kishud as kishud_mod
     if args.action == "start":
         if args.detach:
+            # the daemon gets its own process; this parent never starts a
+            # JAX backend (nothing above touches a device), so the child can
+            # take the accelerator — a chip belongs to one process at a time
             import subprocess
             proc = subprocess.Popen(
                 [sys.executable, "-m", "repro.launch.kishud",

@@ -48,6 +48,7 @@ from repro.core.chunkstore import (ChunkCache, ChunkStore, namespace_views,
                                    open_store)
 from repro.core.lease import lease_status
 from repro.core.session import KishuSession
+from repro.launch.compile_cache import use_compile_cache
 
 INTERACTIVE = 0          # a human is waiting: cell run, checkout
 BACKGROUND = 1           # fleet hygiene: gc, scrub, rebalance
@@ -440,6 +441,7 @@ def main(argv: Optional[list] = None) -> int:
     ap.add_argument("--cache-bytes", type=int, default=None)
     ap.add_argument("--lease-ttl", type=float, default=10.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     daemon = Kishud(args.store, workers=args.workers,
                     cache_bytes=args.cache_bytes,

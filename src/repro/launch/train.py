@@ -19,6 +19,7 @@ import time
 import jax
 
 from repro.core.chunkstore import open_store
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.config import get_config
 from repro.models.testing import reduced as reduce_cfg
 from repro.optim.adamw import AdamWConfig
@@ -41,6 +42,7 @@ def main() -> None:
                     help="rollback a phase if loss spikes by this factor")
     ap.add_argument("--async-write", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -267,11 +267,25 @@ def _run_stages(stages_params: dict, stage_specs: List[StageSpec],
 # forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+def take_rows(table: Array, ids: Array) -> Array:
+    """``table[ids]``, with the result sharded like ``ids`` and replicated
+    along the table's row width.  On an explicitly sharded mesh the table
+    and the ids may both be split over the same axis (embed ``P(model,
+    data)``, tokens ``P(data, None)``), and the gather's inferred output
+    spec would name that axis twice; so the spec is stated."""
+    sharding = jax.typeof(ids).sharding
+    if not sharding.mesh.explicit_axes:
+        return table[ids]
+    spec = jax.sharding.PartitionSpec(*sharding.spec, None)
+    return table.at[ids].get(
+        out_sharding=jax.sharding.NamedSharding(sharding.mesh, spec))
+
+
 def embed_inputs(cfg: ArchConfig, params: dict, batch: dict) -> Array:
     if "embeds" in batch:
         x = batch["embeds"].astype(jnp.dtype(cfg.dtype))
     else:
-        x = params["embed"][batch["tokens"]]
+        x = take_rows(params["embed"], batch["tokens"])
     return x
 
 

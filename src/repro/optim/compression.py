@@ -19,7 +19,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _quantize(g: jax.Array, scale: jax.Array) -> jax.Array:
@@ -48,9 +47,9 @@ def compressed_psum(grads: Any, residual: Any, mesh: Mesh, axis: str
             s = jax.lax.psum(q.astype(jnp.int32), axis)
             return (s.astype(jnp.float32) * scale / n), new_r
 
-        sm = shard_map(body, mesh=mesh,
-                       in_specs=(P(), P()), out_specs=(P(), P()),
-                       check_rep=False)
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(), P()), out_specs=(P(), P()),
+                           check_vma=False)
         return sm(g, r)
 
     flat_g, treedef = jax.tree.flatten(grads)

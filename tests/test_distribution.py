@@ -32,9 +32,10 @@ def test_sharded_train_step_runs_on_8_devices():
         from repro.models.testing import reduced
         from repro.optim.adamw import AdamWConfig
         from repro.train import step as step_lib
+        from repro.launch.mesh import make_local_mesh
         from repro.sharding.rules import ShardingRules
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_local_mesh(model=4)
         cfg = reduced(get_config("qwen3-1.7b"), n_layers=2).replace(
             d_model=64, n_heads=4, n_kv_heads=4, head_dim=16)
         oc = AdamWConfig(lr=1e-3)
@@ -66,6 +67,7 @@ def test_sharded_equals_single_device():
         from repro.models.testing import reduced
         from repro.optim.adamw import AdamWConfig
         from repro.train import step as step_lib
+        from repro.launch.mesh import make_local_mesh
         from repro.sharding.rules import ShardingRules
 
         cfg = reduced(get_config("smollm-360m"), n_layers=2)
@@ -77,7 +79,7 @@ def test_sharded_equals_single_device():
         # single-device reference
         s_ref, m_ref = fn(jax.device_put(state), batch)
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_local_mesh(model=4)
         rules = ShardingRules(cfg, mesh)
         pshard = rules.param_shardings(state["params"])
         sshard = {"params": pshard,
@@ -96,6 +98,31 @@ def test_sharded_equals_single_device():
         print("EQUIV_OK")
     """)
     assert "EQUIV_OK" in out
+
+
+def test_take_rows_states_gather_sharding_on_explicit_mesh():
+    """On an explicitly sharded mesh the embedding table and the token ids
+    are both split over `data`; the gather's output is sharded like the
+    ids instead of raising DuplicateSpecError."""
+    out = run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.models.lm import take_rows
+
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Explicit,) * 2)
+        table = np.arange(64 * 32, dtype=np.float32).reshape(64, 32)
+        ids = np.random.default_rng(0).integers(0, 64, (4, 16))
+        t = jax.device_put(table, NamedSharding(mesh, P("model", "data")))
+        i = jax.device_put(ids.astype(np.int32),
+                           NamedSharding(mesh, P("data", None)))
+        with mesh:
+            got = jax.jit(take_rows)(t, i)
+        assert got.sharding.spec == P("data", None, None), got.sharding
+        np.testing.assert_array_equal(np.asarray(got), table[ids])
+        print("TAKE_ROWS_OK")
+    """)
+    assert "TAKE_ROWS_OK" in out
 
 
 def test_compressed_psum_numerics():
@@ -117,7 +144,6 @@ def test_compressed_psum_numerics():
         # [8, ...] arrays sharded over data, inside shard_map semantics.
         stacked = {"w": jnp.stack([make(i)["w"] for i in range(8)]),
                    "b": jnp.stack([make(i)["b"] for i in range(8)])}
-        from jax.experimental.shard_map import shard_map
         def body(g):
             g = jax.tree.map(lambda x: x[0], g)    # local shard [1,...] -> [...]
             r = jax.tree.map(lambda x: jnp.zeros_like(x), g)
@@ -132,8 +158,8 @@ def test_compressed_psum_numerics():
         sharded = jax.device_put(
             stacked, jax.tree.map(lambda _: jax.NamedSharding(mesh, P("data")), stacked))
         with mesh:
-            out = shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                            out_specs=P("data"), check_rep=False)(sharded)
+            out = jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                                out_specs=P("data"), check_vma=False)(sharded)
         got = jax.tree.map(lambda x: np.asarray(x)[0], out)
         want = {k: np.mean([np.asarray(make(i)[k]) for i in range(8)], axis=0)
                 for k in ("w", "b")}

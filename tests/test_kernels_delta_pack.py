@@ -1,8 +1,9 @@
 """Fused delta_pack kernel + pipeline wiring tests (fast lane).
 
 Covers the kernel contract (hashes / dirty vector / compacted buffer) on
-both backends in interpret mode, VMEM segmenting, the env gate, the
-fallback-counter observability satellite, and the end-to-end guarantee:
+both backends in interpret mode, segmenting, the env gate, the backend
+choice by platform (and a kernel error propagating), and the end-to-end
+guarantee:
 a jax session on the fused path produces bit-identical checkpoints (same
 states, same content-addressed chunk keys) as the host path.
 """
@@ -97,9 +98,9 @@ def test_device_delta_pack_gating(monkeypatch):
 
 
 def test_fallback_counter_and_log_once(monkeypatch, caplog):
-    """exact_dirty_indices degrading to the host compare must bump the
-    session fallback counter and warn exactly once (the observability
-    satellite — a silently slow path is now visible)."""
+    """A device kernel error propagates: exact_dirty_indices must neither
+    swallow it into the host compare nor count it as a fallback (the
+    counter is for storage and replay demotions only)."""
     import importlib
     import logging
 
@@ -108,18 +109,122 @@ def test_fallback_counter_and_log_once(monkeypatch, caplog):
     bd = importlib.import_module("repro.kernels.block_diff.ops")
 
     def boom(*a, **k):
-        raise RuntimeError("no backend")
+        raise RuntimeError("kernel failed")
     monkeypatch.setattr(bd, "dirty_chunks", boom)
     monkeypatch.setattr(delta_mod, "_fallback_logged", False)
     a = jnp.arange(2048, dtype=jnp.float32)
     b = a.at[0].set(9.0)
     before = delta_mod.kernel_fallbacks()
     with caplog.at_level(logging.WARNING, logger="repro.core.delta"):
-        assert delta_mod.exact_dirty_indices(a, b, 1 << 10) == [0]
-        assert delta_mod.exact_dirty_indices(a, b, 1 << 10) == [0]
-    assert delta_mod.kernel_fallbacks() == before + 2
-    warns = [r for r in caplog.records if "device kernel" in r.message]
-    assert len(warns) == 1               # log-once-per-session
+        with pytest.raises(RuntimeError, match="kernel failed"):
+            delta_mod.exact_dirty_indices(a, b, 1 << 10)
+    assert delta_mod.kernel_fallbacks() == before
+    assert not [r for r in caplog.records if "device kernel" in r.message]
+    # host arrays still take the NumPy compare
+    assert delta_mod.exact_dirty_indices(np.asarray(a), np.asarray(b),
+                                         1 << 10) == [0]
+
+
+def _x_and_prev():
+    x = jnp.arange(4096, dtype=jnp.float32)
+    prev = H.chunk_hashes_np(np.asarray(x).tobytes(), 1 << 10)
+    return x, prev
+
+
+def _boom(*a, **k):
+    raise RuntimeError("kernel failed")
+
+
+# each device entry point: (call, module holding the Pallas function, name)
+_ENTRY_POINTS = {
+    "chunk_hashes_device": (
+        lambda: H.chunk_hashes_device(_x_and_prev()[0], 1 << 10),
+        "repro.kernels.chunk_hash.ops", "chunk_hash_pallas"),
+    "device_delta_pack": (
+        lambda: delta_mod.device_delta_pack(*_x_and_prev(), 1 << 10),
+        "repro.kernels.delta_pack.kernel", "delta_pack_pallas"),
+    "patch_device_chunks": (
+        lambda: delta_mod.patch_device_chunks(
+            _x_and_prev()[0], [(1024, b"\x07" * 1024)], 1 << 10),
+        "repro.kernels.patch_scatter.kernel", "patch_scatter_pallas"),
+    "exact_dirty_indices": (
+        lambda: delta_mod.exact_dirty_indices(
+            _x_and_prev()[0], _x_and_prev()[0].at[0].set(1.0), 1 << 10),
+        "repro.kernels.block_diff.ops", "block_diff_pallas"),
+}
+
+
+def _force_device_paths(monkeypatch):
+    for gate in ("KISHU_DEVICE_DELTA", "KISHU_DEVICE_HASH",
+                 "KISHU_DEVICE_SCATTER"):
+        monkeypatch.setenv(gate, "1")
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRY_POINTS))
+def test_platform_selects_reference_on_cpu(monkeypatch, entry):
+    """Off a TPU the backend is the jnp reference, chosen by platform: the
+    Pallas function is never called, and no fallback is counted."""
+    import importlib
+
+    from repro.kernels.common import platform_backend
+
+    assert platform_backend() == "ref"
+    assert platform_backend(jnp.zeros(4)) == "ref"
+    call, module, name = _ENTRY_POINTS[entry]
+    _force_device_paths(monkeypatch)
+    monkeypatch.setattr(importlib.import_module(module), name, _boom)
+    before = delta_mod.kernel_fallbacks()
+    assert call() is not None
+    assert delta_mod.kernel_fallbacks() == before
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRY_POINTS))
+def test_kernel_error_propagates(monkeypatch, entry):
+    """Where the platform selects the Pallas kernel, its error reaches the
+    caller: no try-the-next-backend ladder, no host path, no counter."""
+    import importlib
+    import sys
+
+    call, module, name = _ENTRY_POINTS[entry]
+    _force_device_paths(monkeypatch)
+    call_module = importlib.import_module(module)
+    for mod in [m for n, m in list(sys.modules.items())
+                if n.startswith("repro.") and hasattr(m, "platform_backend")]:
+        monkeypatch.setattr(mod, "platform_backend", lambda x=None: "pallas")
+    monkeypatch.setattr(call_module, name, _boom)
+    before = delta_mod.kernel_fallbacks()
+    with pytest.raises(RuntimeError, match="kernel failed"):
+        call()
+    assert delta_mod.kernel_fallbacks() == before
+
+
+def test_sharded_array_takes_host_path(monkeypatch):
+    """An array spread over several devices is refused by an explicit
+    check before any kernel runs."""
+    from repro.core.delta import device_kernel_applies
+
+    x, prev = _x_and_prev()
+    assert device_kernel_applies(x)
+
+    class _TwoDevices:
+        device_set = {"d0", "d1"}
+
+    class _Sharded:
+        def __init__(self, arr):
+            self._arr = arr
+
+        def __getattr__(self, name):
+            return getattr(self._arr, name)
+
+        sharding = _TwoDevices()
+
+    import jax
+    monkeypatch.setattr(jax, "Array", (type(x), _Sharded))
+    _force_device_paths(monkeypatch)
+    sharded = _Sharded(x)
+    assert not device_kernel_applies(sharded)
+    assert delta_mod.device_delta_pack(sharded, prev, 1 << 10) is None
+    assert H.chunk_hashes_device(sharded, 1 << 10) is None
 
 
 def _session_states(store, force: str, chunk_bytes=1 << 12):

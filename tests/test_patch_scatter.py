@@ -1,13 +1,14 @@
 """Fused device-scatter checkout: kernel parity, dtype round-trips, the
-patch_device_chunks contract + fallback ladder, and end-to-end checkout
-bit-identity with the scatter forced on (fast lane).
+patch_device_chunks contract, and end-to-end checkout bit-identity with
+the scatter forced on (fast lane).
 
 The invariant under test everywhere: scattering the dirty chunks of a
 co-variable in ONE pass (kernels/patch_scatter, Pallas via interpret on
 CPU) restores exactly the bytes the per-chunk ``dynamic_update_slice``
-loop would have — on every supported dtype, alignment and tail shape —
-and every reason the fused path disengages routes through
-``note_kernel_fallback`` instead of dying or silently corrupting.
+loop would have — on every supported dtype, alignment and tail shape.
+An input the fused path does not take is refused up front (``None``); a
+missing patch chunk demotes to a full load and is counted; a kernel error
+propagates.
 """
 import numpy as np
 import pytest
@@ -81,7 +82,7 @@ def test_scatter_roundtrip_dtypes(backend, dtype):
 
 @pytest.mark.parametrize("dtype", ["uint64", "int64", "float64"])
 def test_scatter_roundtrip_wide_dtypes(dtype):
-    from jax.experimental import enable_x64
+    import jax
     import jax.numpy as jnp
 
     rng = np.random.default_rng(11)
@@ -93,7 +94,7 @@ def test_scatter_roundtrip_wide_dtypes(dtype):
     cb = 128
     blob = rng.integers(0, 250, cb, dtype=np.uint8).tobytes()
     target_np.view(np.uint8)[cb:2 * cb] = np.frombuffer(blob, np.uint8)
-    with enable_x64():
+    with jax.enable_x64(True):
         got, _ = _scatter(jnp.asarray(base_np), [1], [blob], cb, "pallas")
         assert np.asarray(got).tobytes() == target_np.tobytes()
         assert got.dtype == base_np.dtype
@@ -263,4 +264,25 @@ def test_fetch_patch_chunks_fallback_routes_through_counter(tmp_path,
     want = np.arange(1 << 14, dtype=np.int32) % 89
     want[np.arange(3) * 1024] = 3
     assert np.array_equal(np.asarray(sess.ns["v"]), want)
+    sess.close()
+
+
+def test_checkout_scatter_kernel_error_propagates(monkeypatch):
+    """A failing scatter is a device fault, not a corrupt patch: the
+    checkout raises instead of reloading the co-variable, and counts no
+    fallback."""
+    from repro.core import MemoryStore
+    from repro.kernels.patch_scatter import ops as scatter_ops
+
+    sess = _mk_session(MemoryStore(), monkeypatch)
+    cid = sess.run("mutate", seed=5)
+    sess.run("mutate", seed=6)
+
+    def boom(*a, **k):
+        raise RuntimeError("scatter kernel failed")
+    monkeypatch.setattr(scatter_ops, "scatter_chunks", boom)
+    fb0 = delta_mod._kernel_fallbacks
+    with pytest.raises(RuntimeError, match="scatter kernel failed"):
+        sess.checkout(cid)
+    assert delta_mod._kernel_fallbacks == fb0
     sess.close()
