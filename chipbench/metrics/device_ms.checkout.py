@@ -1,0 +1,14 @@
+"""Device time per checkout: milliseconds in which an operation ran on the
+device (the union of the ``XLA Ops`` intervals) inside the window's
+checkouts, each timed until the restored state is on the device, over the
+checkouts.  Copies between host and device are not operations and do not
+count, so a full load alone reads nothing.  Moves ``checkout_s``."""
+from chipbench import trace
+
+
+def read(ctx):
+    spans = ctx.annotated("checkout")
+    if not spans or not ctx.trace.devices:
+        return None
+    busy = sum(trace.overlap(ctx.busy(), a, b) for a, b in spans)
+    return 1e-6 * busy / len(spans) if busy > 0 else None
