@@ -15,14 +15,16 @@ round-trips.
 """
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
-from contextlib import nullcontext
-from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import (Any, Dict, Iterable, Iterator, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
+from repro import obs
 from repro.core import delta as delta_mod
 from repro.core import parallel
 from repro.core.chunkstore import ChunkCache, ChunkStore
@@ -103,13 +105,21 @@ def materialize_manifest(store: ChunkStore, manifest: dict,
     if manifest.get("unserializable"):
         raise SerializationError("manifest flagged unserializable")
     base_info = manifest["base"]
+    have = chunks or {}
+    read: Dict[int, bytes] = {}          # chunk position -> bytes read here
+    need = [i for i, c in enumerate(base_info["chunks"])
+            if have.get(c["key"]) is None]
+    if need:
+        with obs.span("read") as sp:
+            for i in need:
+                read[i] = store.get_chunk(base_info["chunks"][i]["key"])
+            moved = sum(len(d) for d in read.values())
+            sp.set(bytes=moved)
+        if stats:
+            stats.bytes_loaded += moved
     parts = []
-    for c in base_info["chunks"]:
-        data = chunks.get(c["key"]) if chunks is not None else None
-        if data is None:
-            data = store.get_chunk(c["key"])
-            if stats:
-                stats.bytes_loaded += len(data)
+    for i, c in enumerate(base_info["chunks"]):
+        data = read[i] if i in read else have[c["key"]]
         if len(data) != c["n"]:
             raise ChunkMissingError(f"chunk {c['key']}: size mismatch")
         parts.append(data)
@@ -151,6 +161,21 @@ def records_from_manifest(manifest: dict, values: Dict[str, Any]
     return out
 
 
+def _waited(pairs: Iterable[Tuple[List[str], Dict[str, bytes]]]
+            ) -> Iterator[Tuple[List[str], Dict[str, bytes]]]:
+    """The (slab, chunks) pairs of a prefetch pipeline, each wait for the
+    next one a ``read`` span of the calling thread."""
+    it = iter(pairs)
+    while True:
+        with obs.span("read") as sp:
+            nxt = next(it, None)
+            sp.set(bytes=0 if nxt is None
+                   else sum(len(v) for v in nxt[1].values()))
+        if nxt is None:
+            return
+        yield nxt
+
+
 class StateLoader:
     def __init__(self, graph: CheckpointGraph, store: ChunkStore,
                  fallback=None, *, io_threads: Optional[int] = None,
@@ -176,10 +201,6 @@ class StateLoader:
         # not off); None keeps the fixed patch->fetch->fallback ladder
         self.planner = None
 
-    def _span(self, name: str, **args):
-        return self.obs.span(name, **args) if self.obs is not None \
-            else nullcontext()
-
     def _cache_probe(self, keys, stats: Optional[CheckoutStats]
                      ) -> Dict[str, bytes]:
         """Chunks served by the shared cache (accounted as cached bytes)."""
@@ -194,7 +215,8 @@ class StateLoader:
     def _fetch_parallel(slabs, fetch, consume, workers):
         """Stream ``slabs`` through the prefetch pipeline; returns [] (all
         consumed) so callers can fall through to the serial remainder."""
-        for slab, got in parallel.prefetch_map(fetch, slabs, workers):
+        for slab, got in _waited(parallel.prefetch_map(fetch, slabs,
+                                                       workers)):
             consume(slab, got)
         return []
 
@@ -281,6 +303,14 @@ class StateLoader:
                 with parallel.serial_section():
                     return slab, self.store.get_chunks(slab, missing_ok=True)
 
+            def read(slab):
+                # a fetch on this thread; the pool's are spanned by this
+                # thread's wait for them (_waited)
+                with obs.span("read") as sp:
+                    got = fetch(slab)
+                    sp.set(bytes=sum(len(v) for v in got[1].values()))
+                return got
+
             def finish(i):
                 key, version, manifest, cks = ready[i]
                 try:
@@ -323,14 +353,14 @@ class StateLoader:
             if slabs:
                 # Slab 0 absorbs cold-start effects (cache revalidation,
                 # first touch) so the probe compares steady-state rates.
-                consume(*fetch(slabs[0]))
+                consume(*read(slabs[0]))
                 rest = slabs[1:]
                 if self.probe_threshold_s <= 0:     # forced pipeline
                     rest = self._fetch_parallel(rest, fetch, consume, workers)
                 elif rest:
                     # Probe: one slab on the calling thread, timed.
                     t0 = time.perf_counter()
-                    slab1, got1 = fetch(rest[0])
+                    slab1, got1 = read(rest[0])
                     dt = max(time.perf_counter() - t0, 1e-9)
                     consume(slab1, got1)
                     per_chunk_serial = dt / max(1, len(slab1))
@@ -344,8 +374,11 @@ class StateLoader:
                         # above excludes consume() too.
                         trial, rest = rest[:workers], rest[workers:]
                         t0 = time.perf_counter()
-                        trial_got = parallel.map_parallel(
-                            lambda s: fetch(s)[1], trial, workers)
+                        with obs.span("read") as sp:
+                            trial_got = parallel.map_parallel(
+                                lambda s: fetch(s)[1], trial, workers)
+                            sp.set(bytes=sum(len(v) for got in trial_got
+                                             for v in got.values()))
                         dt2 = max(time.perf_counter() - t0, 1e-9)
                         for slab, got in zip(trial, trial_got):
                             consume(slab, got)
@@ -356,7 +389,7 @@ class StateLoader:
                             rest = self._fetch_parallel(rest, fetch, consume,
                                                         workers)
                 for slab in rest:                   # serial remainder
-                    consume(*fetch(slab))
+                    consume(*read(slab))
 
         for key, version in retry:
             manifest = self.graph.manifest_of(key, version)
@@ -582,7 +615,9 @@ class StateLoader:
                     box["out"] = self.load_covs(full_items, fstats)
                 except BaseException as e:  # noqa: BLE001 — raised on join
                     box["err"] = e
-            th = threading.Thread(target=_fetch_lane,
+            # the lane runs in this context: its spans nest under ours
+            th = threading.Thread(target=contextvars.copy_context().run,
+                                  args=(_fetch_lane,),
                                   name="kishu-fetch-lane", daemon=True)
             th.start()
         loaded: Dict[CovKey, Dict[str, Any]] = {}
@@ -622,7 +657,7 @@ class StateLoader:
         #    live buffer matches the target structurally only fetch their
         #    differing chunks
         replay_items: List[Tuple[CovKey, str]] = []
-        with self._span("plan"):
+        with obs.span("plan"):
             plan: CheckoutPlan = self.graph.diff(cur, target)
             stats.diff_s = time.perf_counter() - td
             stats.covs_identical = len(plan.identical)
@@ -638,7 +673,7 @@ class StateLoader:
                 stats.covs_planned_fetch = len(full_items)
                 stats.covs_planned_replay = len(replay_items)
                 stats.plan_est_s = priced.est_total_s
-        with self._span("fetch"):
+        with obs.span("fetch"):
             patch_data, patches, demoted = self._fetch_patch_chunks(patches,
                                                                     stats)
         full_items = sorted(full_items + demoted)
@@ -647,14 +682,14 @@ class StateLoader:
         #    chunk I/O planned up front and prefetched in parallel; with a
         #    planner mixed plan the fetch slabs stream on a helper thread
         #    while replays run here
-        with self._span("materialize",
+        with obs.span("materialize",
                         covs=len(full_items) + len(replay_items)):
             loaded = self._materialize_mixed(full_items, replay_items, stats)
 
         # 3. apply patches (all data is in hand); a missing or short patch
         #    chunk falls back to the full serial load of just that
         #    co-variable — any other error (a device kernel's) raises
-        with self._span("patch", covs=len(patches)):
+        with obs.span("patch", covs=len(patches)):
             for p in patches:
                 try:
                     loaded[p.key] = self._apply_patch(p, patch_data, stats,
@@ -665,7 +700,7 @@ class StateLoader:
 
         # 4. swap into the namespace (tracking paused: checkout is not access)
         new_records = dict(records)
-        with self._span("swap"), tracked_ns.pause():
+        with obs.span("swap"), tracked_ns.pause():
             for key in plan.to_delete:
                 for name in key:
                     if name in tracked_ns.base:

@@ -19,12 +19,12 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from contextlib import nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import delta as delta_mod
 from repro.core import hashing
 from repro.core.chunkstore import ChunkCache, ChunkStore, chunk_key
@@ -46,7 +46,10 @@ class WriteStats:
     chunks_dedup: int = 0           # skipped via CAS hit
     covs_delta: int = 0             # covs written via the dirty-range path
     covs_packed: int = 0            # subset served by the fused device pack
-    bytes_dev2host: int = 0         # device→host bytes the pack(s) moved
+    bytes_dev2host: int = 0         # device→host bytes the commit moved:
+                                    # pack syncs and rows, codec planes,
+                                    # whole leaves, ranges (the session's
+                                    # kishu_d2h_bytes_total over the commit)
     chunks_encoded: int = 0         # chunks compressed on device (bit-plane
                                     # frames crossed PCIe, not raw rows)
     chunks_codec_skipped: int = 0   # probe said incompressible → raw
@@ -75,10 +78,29 @@ def _pack_usable(pack, det_hex: List[str], dirty_set, n: int,
     return dirty_set <= pack.dirty_set
 
 
+class _Stopwatch:
+    """``fn``, timed: the seconds spent in it summed over its calls (a
+    manifest span's per-chunk key hashing and dedup lookups)."""
+    __slots__ = ("fn", "seconds", "calls")
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self.seconds = 0.0
+        self.calls = 0
+
+    def __call__(self, *args):
+        t0 = time.perf_counter()
+        try:
+            return self.fn(*args)
+        finally:
+            self.seconds += time.perf_counter() - t0
+            self.calls += 1
+
+
 def _try_delta_manifest(base, det_hex: List[str], prev_manifest,
                         chunk_bytes: int, stats: WriteStats,
                         put, has, members, pack=None,
-                        put_stored=None) -> Optional[dict]:
+                        put_stored=None, key_fn=chunk_key) -> Optional[dict]:
     """Dirty-range fast path: when the previous manifest matches this base
     structurally, compare detection hashes *first* and serialize only the
     dirty byte ranges — the full blob is never built and device→host
@@ -128,7 +150,7 @@ def _try_delta_manifest(base, det_hex: List[str], prev_manifest,
     def _store(i: int, cdata, frame=None) -> None:
         # the key is ALWAYS over the logical bytes — codec frames are a
         # storage representation, invisible to dedup and manifests
-        ck = chunk_key(cdata)
+        ck = key_fn(cdata)
         if has(ck):
             stats.chunks_dedup += 1
         elif frame is not None and put_stored is not None:
@@ -160,16 +182,21 @@ def _try_delta_manifest(base, det_hex: List[str], prev_manifest,
                 _store(i, cdata)
         stats.chunks_encoded += pack.codec_chunks_encoded - enc0
         stats.chunks_codec_skipped += pack.codec_chunks_skipped - skip0
-        stats.bytes_dev2host += pack.bytes_transferred
     else:
-        for start, stop in delta_mod.coalesce(dirty):
+        runs = delta_mod.coalesce(dirty)
+        moved = 0
+        for start, stop in runs:
             lo, hi = start * chunk_bytes, min(stop * chunk_bytes, n)
             data = reader(lo, hi)
-            stats.bytes_serialized += len(data)
+            moved += len(data)
             for i in range(start, stop):
                 clo = i * chunk_bytes - lo
                 chi = min((i + 1) * chunk_bytes, n) - lo
                 _store(i, data[clo:chi])
+        stats.bytes_serialized += moved
+        if not isinstance(base, np.ndarray):    # each run a device copy
+            obs.count("kishu_d2h_bytes_total", moved, path="range")
+            obs.count("kishu_host_syncs_total", len(runs), where="range")
     return {"members": members, "unserializable": False,
             "base": {"meta": meta, "nbytes": n, "chunks": chunks,
                      "det_hashes": det_hex}}
@@ -192,9 +219,30 @@ def build_manifest(store: ChunkStore, key: CovKey,
     that also sees chunks batched/enqueued but not yet landed in the store,
     so deferred (batched or async) puts never double-write within a delta.
     ``delta_ranges=False`` disables the dirty-range fast path (benchmark
-    baseline — the pre-delta cov-granular writer)."""
+    baseline — the pre-delta cov-granular writer).
+
+    Traced, it is one ``manifest`` span per co-variable whose args sum the
+    time of the per-chunk key hashing (``keys_s``) and dedup lookups
+    (``has_s``) over its ``chunks`` keyed chunks."""
     if has is None:
         has = store.has_chunk
+    with obs.span("manifest", key=key_str(key)) as sp:
+        timed = sp is not obs.NULL_SPAN
+        keys = _Stopwatch(chunk_key) if timed else chunk_key
+        lookups = _Stopwatch(has) if timed else has
+        man = _build_manifest(records, ns, chunk_bytes, prev_manifest, stats,
+                              put, lookups, delta_ranges, packs, put_stored,
+                              keys)
+        if timed:
+            sp.set(keys_s=keys.seconds, has_s=lookups.seconds,
+                   chunks=keys.calls)
+        return man
+
+
+def _build_manifest(records: List[LeafRecord], ns,
+                    chunk_bytes: int, prev_manifest: Optional[dict],
+                    stats: WriteStats, put, has, delta_ranges: bool,
+                    packs, put_stored, key_fn) -> dict:
     members = []
     for r in records:
         members.append({"name": r.name, "kind": r.kind, "dtype": r.dtype,
@@ -214,7 +262,7 @@ def build_manifest(store: ChunkStore, key: CovKey,
         man = _try_delta_manifest(base, det_hex, prev_manifest, chunk_bytes,
                                   stats, put, has, members,
                                   pack=(packs or {}).get(id(base)),
-                                  put_stored=put_stored)
+                                  put_stored=put_stored, key_fn=key_fn)
         if man is not None:
             return man
 
@@ -246,7 +294,7 @@ def build_manifest(store: ChunkStore, key: CovKey,
             stats.chunks_reused += 1
             continue
         data = blob[lo:hi]
-        ck = chunk_key(data)
+        ck = key_fn(data)
         if has(ck):
             stats.chunks_dedup += 1
         else:
@@ -289,8 +337,9 @@ class CheckpointWriter:
         # keys immediately before the backend put, so a crashed commit's
         # chunks are journaled and recovery can roll them back exactly
         self.journal: Optional[Callable[[List[str]], None]] = None
-        # observability handle (set by the session): spans opened here from
-        # the async drain thread become roots — contextvars don't cross
+        # observability handle (set by the session): the async drain thread
+        # activates it, and its spans are roots carrying ``op``, the
+        # operation whose chunks they land — contextvars don't cross
         # threads, and off-thread work genuinely is off the commit path
         self.obs = None
         self._q: "queue.Queue" = queue.Queue()
@@ -327,6 +376,8 @@ class CheckpointWriter:
                     saw_sentinel = True
                     break
                 batch.append(nxt)
+            op = batch[0][3]
+            batch = [(ck, data, stored) for ck, data, stored, _ in batch]
             try:
                 journaled = True
                 if self.journal is not None:
@@ -338,7 +389,8 @@ class CheckpointWriter:
                                                 # find them
                 if journaled:
                     try:
-                        with self._span("put_chunks", n=len(batch)):
+                        with obs.activated(self.obs), \
+                                obs.span("put_chunks", n=len(batch), op=op):
                             self._put_batch(batch)
                     except Exception:  # noqa: BLE001
                         # batch op failed somewhere: degrade to per-chunk
@@ -379,7 +431,7 @@ class CheckpointWriter:
             self._enqueued += 1
         if self.async_write:
             self.pending_keys.add(ck)
-            self._q.put((ck, bytes(data), stored))
+            self._q.put((ck, bytes(data), stored, obs.current_op()))
         else:
             self._batch.append((ck, bytes(data), stored))
             self._batch_keys.add(ck)
@@ -399,10 +451,6 @@ class CheckpointWriter:
             self.cache.put(ck, bytes(logical))
         self._enqueue(ck, frame, stored=True)
 
-    def _span(self, name: str, **args):
-        return self.obs.span(name, **args) if self.obs is not None \
-            else nullcontext()
-
     def _flush_batch(self) -> None:
         if not self._batch:
             return
@@ -414,7 +462,7 @@ class CheckpointWriter:
                 # (the exception propagates to run()) so no chunk ever
                 # lands unjournaled
                 self.journal([ck for ck, _, _ in batch])
-            with self._span("put_chunks", n=len(batch)):
+            with obs.span("put_chunks", n=len(batch)):
                 self._put_batch(batch)
         finally:
             # the batch leaves the pipeline on ANY outcome — journal
@@ -454,7 +502,7 @@ class CheckpointWriter:
         t0 = time.perf_counter()
         stats = WriteStats()
         manifests: Dict[str, dict] = {}
-        with self._span("serialize", covs=len(delta.updated)):
+        with obs.span("serialize", covs=len(delta.updated)):
             for key, records in delta.updated.items():
                 man = build_manifest(self.store, key, records, ns,
                                      self.chunk_bytes, prev_manifest_of(key),

@@ -18,49 +18,26 @@ the dirty ranges cross the device→host boundary.
 """
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 _log = logging.getLogger(__name__)
-
-# Device-kernel fallback observability: every silent degradation to a host
-# path bumps a counter (snapshotted into WriteStats/CheckoutStats per
-# operation) and the *first* one per session logs a warning — a silently
-# slow path must be visible without turning every commit into log spam.
-#
-# The counter lives in the *active session's* metrics registry
-# (repro.obs.active()) when a session is executing: under kishud many
-# sessions share this process, so a module global would cross-attribute
-# tenants and the fb0 delta snapshots would race.  The module globals below
-# remain as a deprecated process-wide shim for callers running outside any
-# session (tests, ad-hoc kernel use).
-_kernel_fallbacks = 0
-_fallback_logged = False
-
-
-def _active_obs():
-    try:
-        from repro import obs as _obs
-        return _obs.active()
-    except Exception:  # noqa: BLE001 — obs must never break the hot path
-        return None
 
 
 def note_kernel_fallback(where: str, err: Exception) -> None:
-    """Record one device-kernel → host-path degradation."""
-    global _kernel_fallbacks, _fallback_logged
-    _kernel_fallbacks += 1          # process-wide shim stays monotonic
-    o = _active_obs()
-    if o is not None:
-        first = o.note_kernel_fallback(where)
-    else:
-        first = not _fallback_logged
-        _fallback_logged = True
-    if first:
+    """Record one storage or replay demotion to a slower path: the active
+    session's ``kishu_kernel_fallbacks_total`` (snapshotted into
+    WriteStats/CheckoutStats per operation), and a warning the first time
+    in that session — a silently slow path must be visible without turning
+    every commit into log spam.  Outside any session nothing is counted
+    and each call logs."""
+    o = obs.active()
+    if o is None or o.note_kernel_fallback(where):
         _log.warning(
             "device kernel unavailable in %s (%s: %s); using the host path. "
             "Logged once per session — see the kernel_fallbacks counter in "
@@ -73,20 +50,14 @@ def note_kernel_call(kernel: str, backend: str) -> None:
     (``kishu_kernel_calls_total{kernel,backend}`` in the active session's
     registry) — how a run shows whether the Pallas kernel or the jnp
     reference did the work."""
-    o = _active_obs()
-    if o is not None:
-        o.registry.counter("kishu_kernel_calls_total", kernel=kernel,
-                           backend=backend).inc()
+    obs.count("kishu_kernel_calls_total", kernel=kernel, backend=backend)
 
 
 def kernel_fallbacks() -> int:
-    """Total device-kernel fallbacks — scoped to the active session's
-    metrics registry when one is executing; otherwise the (deprecated)
-    process-wide total."""
-    o = _active_obs()
-    if o is not None:
-        return o.kernel_fallbacks()
-    return _kernel_fallbacks
+    """The active session's kernel fallbacks so far (0 outside a
+    session)."""
+    o = obs.active()
+    return 0 if o is None else o.kernel_fallbacks()
 
 
 def dirty_indices(prev_hex: Sequence[str], cur_hex: Sequence[str]) -> List[int]:
@@ -218,12 +189,9 @@ def device_delta_pack(base: Any, prev_hashes, chunk_bytes: int):
     from repro.kernels.common import platform_backend
     from repro.kernels.delta_pack.ops import delta_pack
 
-    o = _active_obs()
-    span = o.span("delta_pack", nbytes=nbytes) if o is not None \
-        else contextlib.nullcontext()
     backend = platform_backend(base)
     note_kernel_call("delta_pack", backend)
-    with span:
+    with obs.span("delta_pack", nbytes=nbytes):
         return delta_pack(base, prev, chunk_bytes, backend=backend)
 
 
@@ -283,20 +251,10 @@ def patch_device_chunks(base: Any, segs: Sequence[Tuple[int, bytes]],
     from repro.kernels.common import platform_backend
     from repro.kernels.patch_scatter.ops import scatter_chunks
 
-    o = _active_obs()
-    span = o.span("scatter_dev", chunks=len(idx)) if o is not None \
-        else contextlib.nullcontext()
     backend = platform_backend(base)
     note_kernel_call("patch_scatter", backend)
-    with span:
-        out, moved = scatter_chunks(base, idx, blobs, chunk_bytes,
-                                    backend=backend)
-    if o is not None:
-        try:
-            o.registry.counter("kishu_h2d_bytes_total").inc(moved)
-        except Exception:  # noqa: BLE001 — metrics are best-effort
-            pass
-    return out, moved
+    with obs.span("scatter_dev", chunks=len(idx)):
+        return scatter_chunks(base, idx, blobs, chunk_bytes, backend=backend)
 
 
 def patch_device_array(base: Any, segs: Sequence[Tuple[int, bytes]]) -> Any:
@@ -321,10 +279,13 @@ def patch_device_array(base: Any, segs: Sequence[Tuple[int, bytes]]) -> Any:
         else:
             merged.append((off, [data]))
         end = off + len(data)
+    moved = 0
     for off, parts in merged:
         seg = np.frombuffer(b"".join(parts), dtype=dt)
+        moved += seg.nbytes
         flat = jax.lax.dynamic_update_slice(
             flat, jnp.asarray(seg), (off // item,))
+    obs.count("kishu_h2d_bytes_total", moved, path="patch")
     return flat.reshape(base.shape)
 
 

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 class SerializationError(Exception):
     """Raised when a leaf cannot be serialized (paper §5.1: skip storage,
@@ -92,13 +94,35 @@ def leaf_meta(x: Any) -> dict:
     return {"kind": "object", "type": type(x).__name__}
 
 
+def _to_host(x: Any) -> np.ndarray:
+    """``np.asarray(x)``; for a device array, the counted, spanned
+    device→host copy of the whole leaf."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    nbytes = int(x.size) * np.dtype(x.dtype).itemsize
+    with obs.span("d2h", bytes=nbytes):
+        arr = np.asarray(x)
+    obs.count("kishu_d2h_bytes_total", arr.nbytes, path="leaf")
+    obs.count("kishu_host_syncs_total", where="leaf")
+    return arr
+
+
+def _to_device(arr: np.ndarray) -> jax.Array:
+    """``jnp.asarray(arr)``: the counted, spanned host→device upload of a
+    loaded leaf."""
+    with obs.span("h2d", bytes=int(arr.nbytes)):
+        out = jnp.asarray(arr)
+    obs.count("kishu_h2d_bytes_total", arr.nbytes, path="load")
+    return out
+
+
 def leaf_to_bytes(x: Any) -> Tuple[bytes, dict]:
     """Serialize a *base* leaf. Raises SerializationError for opaque leaves."""
     meta = leaf_meta(x)
     if meta["kind"] == "prng":
-        return np.asarray(jax.random.key_data(x)).tobytes(), meta
+        return _to_host(jax.random.key_data(x)).tobytes(), meta
     if meta["kind"] == "array":
-        arr = np.asarray(x)
+        arr = _to_host(x)
         if not arr.flags["C_CONTIGUOUS"]:
             arr = np.ascontiguousarray(arr)
         return arr.tobytes(), meta
@@ -114,7 +138,7 @@ def leaf_from_bytes(data: bytes, meta: dict, *, device_put: bool = True) -> Any:
     if meta["kind"] == "prng":
         raw = np.frombuffer(data, dtype=np.dtype(meta["dtype"])) \
             .reshape(meta["shape"]).copy()
-        return jax.random.wrap_key_data(jnp.asarray(raw))
+        return jax.random.wrap_key_data(_to_device(raw))
     if meta["kind"] == "array":
         if meta.get("dtype_descr"):
             dt = np.dtype([tuple(d) for d in meta["dtype_descr"]])
@@ -122,7 +146,7 @@ def leaf_from_bytes(data: bytes, meta: dict, *, device_put: bool = True) -> Any:
             dt = np.dtype(meta["dtype"])
         arr = np.frombuffer(data, dtype=dt).reshape(meta["shape"]).copy()
         if meta.get("jax") and device_put:
-            return jnp.asarray(arr)
+            return _to_device(arr)
         return arr
     return pickle.loads(data)
 

@@ -69,6 +69,7 @@ class _RunPlan:
     stats: RunStats
     t_all: float
     fb0: int = 0                     # kernel-fallback counter at plan start
+    d2h0: int = 0                    # device→host bytes at plan start
 
 
 class KishuSession:
@@ -266,6 +267,7 @@ class KishuSession:
         stats = RunStats()
         t_all = time.perf_counter()
         fb0 = delta_mod.kernel_fallbacks()
+        d2h0 = self.obs.d2h_bytes()
 
         self.tracked.reset()
         t0 = time.perf_counter()
@@ -301,7 +303,7 @@ class KishuSession:
             if ver is not None and any(n in dep_names for n in key):
                 deps[key] = ver
         return _RunPlan(name=name, args=args, delta=delta, deps=deps,
-                        stats=stats, t_all=t_all, fb0=fb0)
+                        stats=stats, t_all=t_all, fb0=fb0, d2h0=d2h0)
 
     def _execute_commit(self, plan: "_RunPlan", message: str = "") -> str:
         """Stage 2: serialize the delta's dirty ranges into journaled chunk
@@ -315,6 +317,9 @@ class KishuSession:
         stats.write_s = time.perf_counter() - t0
         # degradations anywhere in this run — detection (plan) or write
         wstats.kernel_fallbacks = delta_mod.kernel_fallbacks() - plan.fb0
+        # device→host bytes of this run: detection's pack syncs and the
+        # writer's rows, planes, ranges and whole leaves
+        wstats.bytes_dev2host = self.obs.d2h_bytes() - plan.d2h0
         stats.write = wstats
 
         if self.quota_bytes is not None:

@@ -69,10 +69,10 @@ import os
 import threading
 import time
 import uuid
-from contextlib import nullcontext
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Set
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
+from repro import obs
 from repro.core.chunkstore import ChunkStore, namespace_views
 from repro.core.graph import REFS_DOC, manifest_chunk_keys
 from repro.core.lease import Lease, LeaseError
@@ -163,9 +163,9 @@ class TxnEngine:
         #: optional writer lease checked (and kept renewed) on every
         #: publish; set by the owning session after acquisition
         self.lease: Optional[Lease] = None
-        #: observability handle (set by the session) — used instead of the
-        #: activation contextvar because async publishes run on a worker
-        #: thread that never sees the session's activation
+        #: observability handle (set by the session) — the async publish
+        #: worker activates it, as that thread never sees the session's
+        #: activation; its spans carry ``op``, the commit that queued them
         self.obs = None
         #: per-engine nonce for journal IDs — two engines in one process
         #: share a pid and both start their counters at zero, so pid +
@@ -182,7 +182,8 @@ class TxnEngine:
         self._poisoned: Optional[Exception] = None
         self._worker: Optional[threading.Thread] = None
         self._wake = threading.Condition()
-        self._pending: List[Optional[tuple]] = []   # queued group snapshots
+        # queued (group snapshot, op of the commit that queued it)
+        self._pending: List[Tuple[Optional[tuple], Optional[int]]] = []
         self._busy = False                 # worker holds a popped group
         self._closing = False
         if async_publish:
@@ -193,10 +194,6 @@ class TxnEngine:
     # ------------------------------------------------------------------
     # observability (no-ops until the session attaches a handle)
     # ------------------------------------------------------------------
-    def _span(self, name: str, **args):
-        return self.obs.span(name, **args) if self.obs is not None \
-            else nullcontext()
-
     def _count(self, name: str, **labels) -> None:
         if self.obs is not None:
             self.obs.registry.counter(name, **labels).inc()
@@ -285,7 +282,7 @@ class TxnEngine:
         snap = self._pop_open() if self.early_snapshot else None
         if self.async_publish:
             with self._wake:
-                self._pending.append(snap)
+                self._pending.append((snap, obs.current_op()))
                 self._wake.notify()
         else:
             self._publish_group(snap)
@@ -296,10 +293,11 @@ class TxnEngine:
                 self._wake.wait_for(lambda: self._pending or self._closing)
                 if not self._pending:
                     return            # closing, queue drained
-                item = self._pending.pop(0)
+                item, op = self._pending.pop(0)
                 self._busy = True     # flush() must see pop+publish as one
             try:
-                self._publish_group(item)
+                with obs.activated(self.obs):
+                    self._publish_group(item, op)
             except Exception as e:  # noqa: BLE001 — surfaced on flush
                 self._errors.append(e)     # before _busy clears below, so
             finally:                       # a concurrent flush cannot miss
@@ -343,7 +341,8 @@ class TxnEngine:
         except Exception:  # noqa: BLE001 — backend down: recovery on next
             pass           # open rolls the journal back instead
 
-    def _publish_group(self, snap: Optional[tuple]) -> None:
+    def _publish_group(self, snap: Optional[tuple],
+                       op: Optional[int] = None) -> None:
         """Fence, then publish one group.  ``snap`` is the group snapshot
         when it was detached at kick time (early_snapshot); ``None`` means
         detach here, *after* the fence — required for the async chunk
@@ -356,7 +355,7 @@ class TxnEngine:
         with self._pub_lock:
             t0 = time.perf_counter()
             if self.fence is not None:
-                with self._span("epoch_fence"):
+                with obs.span("epoch_fence", op=op):
                     try:
                         token = self.fence_token() if self.fence_token \
                             else None
@@ -395,7 +394,8 @@ class TxnEngine:
                 raise TxnError("publish refused: another writer owns this "
                                "branch; transaction rolled back") from e
             t0 = time.perf_counter()
-            with self._span("publish", commits=rec.get("n_commits", 0)):
+            with obs.span("publish", commits=rec.get("n_commits", 0),
+                          op=op):
                 rec["status"] = STATUS_PUBLISH
                 # the point of no return rides the atomic publish itself:
                 # the base record (first) flips the journal to roll-forward,
@@ -612,20 +612,10 @@ def recover(store: ChunkStore) -> Dict[str, int]:
 def _note_recovery(out: Dict[str, int]) -> None:
     """Attribute a recovery's work to the opening session's metrics (the
     session activates its obs handle around graph construction)."""
-    if not (out["replayed"] or out["rolled_back"]):
-        return
-    try:
-        from repro import obs as obs_mod
-        o = obs_mod.active()
-        if o is None:
-            return
-        for kind in ("replayed", "rolled_back", "commits_published",
-                     "chunks_dropped"):
-            if out[kind]:
-                o.registry.counter("kishu_txn_recover_total",
-                                   kind=kind).inc(out[kind])
-    except Exception:  # noqa: BLE001 — observability must not fail recovery
-        pass
+    for kind in ("replayed", "rolled_back", "commits_published",
+                 "chunks_dropped"):
+        if out[kind]:
+            obs.count("kishu_txn_recover_total", out[kind], kind=kind)
 
 
 # ---------------------------------------------------------------------------

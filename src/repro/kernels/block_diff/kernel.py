@@ -38,5 +38,6 @@ def block_diff_pallas(a_words: jax.Array, b_words: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_chunks, 1, LANES), jnp.int32),
         interpret=interpret,
+        name="block_diff_pallas",
     )(a, b)
     return out[:, 0, 0]

@@ -93,6 +93,7 @@ def _hash_call(tiles: jax.Array, nbytes: jax.Array, interpret: bool):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, LANES), jnp.int32),
         interpret=interpret,
+        name="chunk_hash_pallas",
     )(nbytes, tiles)
     return out[:, 0, :2]
 

@@ -91,6 +91,7 @@ def codec_encode_pallas(rows: jax.Array, *, gw: int,
         out_shape=[jax.ShapeDtypeStruct((ng, PLANES, pw), jnp.int32),
                    jax.ShapeDtypeStruct((ng, 1, LANES), jnp.int32)],
         interpret=interpret,
+        name="codec_encode_pallas",
     )(jax.lax.bitcast_convert_type(t, jnp.int32))
     u32 = functools.partial(jax.lax.bitcast_convert_type,
                             new_dtype=jnp.uint32)

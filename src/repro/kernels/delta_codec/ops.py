@@ -17,6 +17,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.kernels.delta_codec import host
 
 _MIN_ROW_PAD = 8
@@ -65,7 +66,10 @@ def encode_rows(rows, *, width: Optional[int] = None,
         masks_d, _count, planes_d = codec_encode_ref(rows, gw=gw)
     else:
         raise ValueError(f"unknown codec backend {backend!r}")
-    masks = np.asarray(masks_d)[: r * gpr].astype("<u4")
+    with obs.span("d2h", bytes=int(masks_d.nbytes)):
+        masks = np.asarray(masks_d)[: r * gpr].astype("<u4")
+    obs.count("kishu_d2h_bytes_total", masks_d.nbytes, path="codec")
+    obs.count("kishu_host_syncs_total", where="read_chunks")
     n_stored = int(host.popcount_u32(masks[:, 0]).sum())
     return masks, planes_d[:n_stored], gw
 
@@ -82,4 +86,6 @@ def probe_device_rows(rows, max_rows: int = 4,
     w = int(np.prod(rows.shape[1:]))
     step = max(1, (take * w) // sample_words)
     sample = np.asarray(rows[:take].reshape(-1)[::step][:sample_words])
+    obs.count("kishu_d2h_bytes_total", sample.nbytes, path="codec")
+    obs.count("kishu_host_syncs_total", where="read_chunks")
     return host.estimate_stored_fraction(sample) < host.PROBE_THRESHOLD

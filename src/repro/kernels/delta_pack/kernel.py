@@ -100,6 +100,7 @@ def delta_pack_pallas(words: jax.Array, prev: jax.Array, nbytes: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((n_chunks, 1, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((n_chunks, rows, LANES), jnp.int32)],
         interpret=interpret,
+        name="delta_pack_pallas",
     )(nbytes.astype(jnp.int32), i32(prev).reshape(-1), i32(words))
     u32 = functools.partial(jax.lax.bitcast_convert_type,
                             new_dtype=jnp.uint32)

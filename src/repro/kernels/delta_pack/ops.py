@@ -31,24 +31,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import hashing
 from repro.kernels.chunk_hash.ops import chunk_nbytes, chunk_rows
 from repro.kernels.common import TILE_WORDS, platform_backend, tile_words
 
 DEFAULT_SEG_BYTES = 4 << 20      # bytes of chunks per kernel launch
-
-
-def _obs_span(name: str, **args):
-    """Span on the active SessionObs, or a no-op outside a session."""
-    import contextlib
-    try:
-        from repro import obs as _obs
-        o = _obs.active()
-        if o is not None:
-            return o.span(name, **args)
-    except Exception:  # noqa: BLE001 — observability is best-effort
-        pass
-    return contextlib.nullcontext()
 
 
 @dataclass
@@ -130,8 +118,11 @@ class DeltaPack:
                     plan[k + 1][0].buf.copy_to_host_async()
                 except AttributeError:
                     pass
-            host = np.asarray(seg.buf)          # blocks on this segment only
+            with obs.span("d2h", bytes=int(seg.buf.nbytes)):
+                host = np.asarray(seg.buf)      # blocks on this segment only
             self.bytes_transferred += host.nbytes
+            obs.count("kishu_d2h_bytes_total", host.nbytes, path="pack")
+            obs.count("kishu_host_syncs_total", where="read_chunks")
             rowmap = {int(ci): r for r, ci in enumerate(seg.dirty)}
             raw = host.reshape(host.shape[0], -1).view(np.uint8)
             for ci in sel:
@@ -177,7 +168,7 @@ class DeltaPack:
         enc: List[tuple] = []
         for seg, _sel in plan:
             note_kernel_call("delta_codec", backend)
-            with _obs_span("encode_dev", rows=int(seg.dirty.size)):
+            with obs.span("encode_dev", rows=int(seg.dirty.size)):
                 masks, planes_dev, gw = codec_ops.encode_rows(
                     seg.buf, width=width, backend=backend)
             try:
@@ -189,8 +180,11 @@ class DeltaPack:
         # phase 2: materialize plane streams, assemble per-chunk frames
         for k, (seg, sel) in enumerate(plan):
             masks, planes_dev, gw = enc[k]
-            planes = np.asarray(planes_dev)     # blocks on this DMA only
+            with obs.span("d2h", bytes=int(planes_dev.nbytes)):
+                planes = np.asarray(planes_dev)  # blocks on this DMA only
             self.bytes_transferred += masks.nbytes + planes.nbytes
+            obs.count("kishu_d2h_bytes_total", planes.nbytes, path="codec")
+            obs.count("kishu_host_syncs_total", where="read_chunks")
             gpr = width // gw
             frames = codec_host.frames_from_encoded(
                 masks, planes, gpr, gw,
@@ -255,10 +249,15 @@ def delta_pack(x, prev_hashes, chunk_bytes: int = 1 << 18, *,
         s1 = min(s0 + seg_chunks, n_chunks)
         h, d, _pos, cnt, buf = fn(words[s0:s1], prev32[s0:s1],
                                   jnp.asarray(nb_np[s0:s1]))
-        count = int(np.asarray(cnt)[0, 0])
-        dflags = np.asarray(d).reshape(-1)
-        hash_parts.append(np.asarray(h))
-        moved += (s1 - s0) * 12 + 4          # hash pair + flag (+ count)
+        # the host waits here, three times a segment, for the pass
+        synced = int(cnt.nbytes + d.nbytes + h.nbytes)
+        with obs.span("pack_sync", bytes=synced):
+            count = int(np.asarray(cnt)[0, 0])
+            dflags = np.asarray(d).reshape(-1)
+            hash_parts.append(np.asarray(h))
+        moved += synced                      # hash pair + flag (+ count)
+        obs.count("kishu_d2h_bytes_total", synced, path="pack")
+        obs.count("kishu_host_syncs_total", 3, where="delta_pack")
         gdirty = s0 + np.flatnonzero(dflags).astype(np.int64)
         assert gdirty.size == count, (gdirty.size, count)
         # trim to the valid compacted rows on device — only these rows ever

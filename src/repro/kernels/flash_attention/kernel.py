@@ -114,6 +114,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             _vmem((block_q, hd), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention_pallas",
     )(q, k, v)
 
 

@@ -67,5 +67,6 @@ def patch_scatter_pallas(words: jax.Array, idx: jax.Array, rows: jax.Array,
         out_shape=jax.ShapeDtypeStruct(tiles.shape, jnp.uint32),
         input_output_aliases={1: 0},       # words (first non-scalar) -> out
         interpret=interpret,
+        name="patch_scatter_pallas",
     )(idx, tiles, tile_words(rows))
     return out.reshape(c, r * LANES)[:, :w]

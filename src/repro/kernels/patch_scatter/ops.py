@@ -17,6 +17,7 @@ from typing import Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.kernels.delta_codec.host import pow2ceil
 
 
@@ -104,8 +105,10 @@ def scatter_chunks(x, idx: Sequence[int], blobs: Sequence[bytes],
                                                  np.int32)])
         rows = np.concatenate([rows, np.repeat(rows[:1], kp - k, axis=0)])
     moved = rows.nbytes + idx_np.nbytes
-    idx_d = jnp.asarray(idx_np)
-    rows_d = jnp.asarray(rows)
+    with obs.span("h2d", bytes=moved):
+        idx_d = jnp.asarray(idx_np)
+        rows_d = jnp.asarray(rows)
+    obs.count("kishu_h2d_bytes_total", moved, path="scatter")
     if backend == "pallas":
         from repro.kernels.patch_scatter.kernel import patch_scatter_pallas
         out = patch_scatter_pallas(words, idx_d, rows_d,

@@ -15,6 +15,13 @@ costs one attribute check per call site when off.  Metrics are always on:
 an :class:`InstrumentedStore` times every store op, and pipeline code bumps
 counters/histograms — no store writes of its own, so crash-injection op
 accounting is unchanged.
+
+Library code reaches the executing session through :func:`span` and
+:func:`count`, which do nothing outside a session.  The copies between host
+and device are counted where they happen, once per segment, leaf or batch:
+``kishu_d2h_bytes_total{path}``, ``kishu_h2d_bytes_total{path}`` and
+``kishu_host_syncs_total{where}``; ``kishu_spans_dropped_total`` counts the
+spans a full ring evicted.
 """
 from __future__ import annotations
 
@@ -27,7 +34,7 @@ from typing import Dict, Optional
 from repro.obs.metrics import (Counter, Gauge, Histogram, LATENCY_BASE_S,
                                MetricsRegistry, SIZE_BASE_BYTES, render)
 from repro.obs.trace import (NULL_SPAN, SpanRecord, Tracer, chrome_trace,
-                             spans_from_doc)
+                             current_op, spans_from_doc)
 
 TRACE_META_PREFIX = "obs/trace/"
 
@@ -52,6 +59,25 @@ def active() -> Optional["SessionObs"]:
     return _active_obs.get()
 
 
+def span(name: str, **args):
+    """A span of the session executing on this context, or ``NULL_SPAN``."""
+    o = _active_obs.get()
+    return NULL_SPAN if o is None else o.tracer.span(name, **args)
+
+
+def activated(o: Optional["SessionObs"]):
+    """``o`` activated on this context — a worker thread's, which never
+    sees the session's own activation; a no-op for None."""
+    return contextlib.nullcontext() if o is None else o.activate()
+
+
+def count(name: str, n: float = 1, **labels: str) -> None:
+    """Add ``n`` to a counter of the session executing on this context."""
+    o = _active_obs.get()
+    if o is not None:
+        o.registry.counter(name, **labels).inc(n)
+
+
 class SessionObs:
     """Per-session observability handle: tracer + metrics registry."""
 
@@ -61,9 +87,11 @@ class SessionObs:
             trace = os.environ.get("KISHU_TRACE", "").strip() in (
                 "1", "true", "on")
         self.sid = uuid.uuid4().hex[:12]
-        self.tracer = Tracer(enabled=bool(trace), max_spans=max_spans)
         labels: Dict[str, str] = {"tenant": tenant} if tenant else {}
         self.registry = MetricsRegistry(const_labels=labels)
+        self.tracer = Tracer(
+            enabled=bool(trace), max_spans=max_spans,
+            on_drop=self.registry.counter("kishu_spans_dropped_total").inc)
         self._fallback_logged = False
 
     # ---- spans ----
@@ -94,6 +122,10 @@ class SessionObs:
         return int(self.registry.counter_total(
             "kishu_kernel_fallbacks_total"))
 
+    def d2h_bytes(self) -> int:
+        """Device→host bytes this session has moved, over every path."""
+        return int(self.registry.counter_total("kishu_d2h_bytes_total"))
+
     # ---- persistence ----
 
     def to_doc(self) -> dict:
@@ -104,9 +136,9 @@ class SessionObs:
 
 
 __all__ = [
-    "SessionObs", "active", "Tracer", "SpanRecord", "chrome_trace",
-    "spans_from_doc", "NULL_SPAN", "MetricsRegistry", "Counter", "Gauge",
-    "Histogram", "render", "LATENCY_BASE_S", "SIZE_BASE_BYTES",
-    "InstrumentedStore", "instrument_tree", "backend_label",
-    "TRACE_META_PREFIX",
+    "SessionObs", "active", "activated", "span", "count", "current_op",
+    "Tracer", "SpanRecord", "chrome_trace", "spans_from_doc", "NULL_SPAN",
+    "MetricsRegistry", "Counter", "Gauge", "Histogram", "render",
+    "LATENCY_BASE_S", "SIZE_BASE_BYTES", "InstrumentedStore",
+    "instrument_tree", "backend_label", "TRACE_META_PREFIX",
 ]

@@ -14,23 +14,40 @@ Disabled cost is one attribute check plus returning a shared no-op context
 manager — no allocation, no clock read — so the tracer can stay wired into
 every hot path unconditionally.
 
+While a tracer is enabled, each span also enters a
+``jax.profiler.TraceAnnotation`` named ``kishu.<name>`` with its
+``span_id`` and args as metadata.  When a profiler session is running the
+spans then land on the host plane of its ``.xplane.pb``, on the clock of the
+device trace; without one the annotation is never built.  jax is imported
+lazily, so this module stays importable without it.
+
+Work handed to another thread (the async writer's drain, the publish
+worker) carries ``op``: :func:`current_op` on the thread that queued it —
+the id of the outermost span open there, the operation that caused it.
+
 Export is Chrome trace-event JSON (``ph: "X"`` complete events, µs
 timestamps), loadable in Perfetto / ``chrome://tracing`` with no deps.
 """
 from __future__ import annotations
 
 import contextvars
+import functools
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 # current span id for the *calling* context; shared across tracers — span ids
 # are globally unique per process so a stale id from another tracer can never
 # be mistaken for a parent in this one (records are matched by id).
 _current_span: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
     "kishu_obs_current_span", default=None)
+# id of the outermost open span of the calling context: the operation
+_current_op: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
+    "kishu_obs_current_op", default=None)
+
+ANNOTATION_PREFIX = "kishu."
 
 _ids = iter(range(1, 1 << 62)).__next__
 _ids_lock = threading.Lock()
@@ -54,15 +71,40 @@ class SpanRecord:
     args: Dict[str, Any] = field(default_factory=dict)
 
 
+def current_op() -> Optional[int]:
+    """Span id of the outermost span open in the calling context (None when
+    tracing is off or no span is open)."""
+    return _current_op.get()
+
+
+@functools.lru_cache(maxsize=None)
+def _annotation() -> Optional[type]:
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def _metadata(args: Dict[str, Any]) -> Dict[str, Any]:
+    """Span args as profiler metadata: numbers and strings as they are,
+    anything else by ``str``."""
+    return {k: v if isinstance(v, (int, float, str)) else str(v)
+            for k, v in args.items()}
+
+
 class _NullSpan:
     """Shared no-op context manager returned when tracing is disabled."""
     __slots__ = ()
 
-    def __enter__(self):
-        return None
+    def __enter__(self) -> "_NullSpan":
+        return self
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args: Any) -> None:
+        pass
 
 
 NULL_SPAN = _NullSpan()
@@ -70,7 +112,7 @@ NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("_tracer", "name", "args", "span_id", "parent_id",
-                 "_t0", "_token")
+                 "_t0", "_token", "_op_token", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -80,15 +122,35 @@ class _Span:
         self.parent_id: Optional[int] = None
         self._t0 = 0.0
         self._token = None
+        self._op_token = None
+        self._ann = None
 
     def __enter__(self) -> "_Span":
         self.parent_id = _current_span.get()
         self._token = _current_span.set(self.span_id)
+        if _current_op.get() is None:
+            self._op_token = _current_op.set(self.span_id)
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(ANNOTATION_PREFIX + self.name,
+                            span_id=self.span_id, **_metadata(self.args))
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
+    def set(self, **args: Any) -> None:
+        """Add args known only once the span's work is done (bytes read)."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_metadata(args))
+
     def __exit__(self, *exc) -> bool:
         t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self._op_token is not None:
+            _current_op.reset(self._op_token)
         if self._token is not None:
             _current_span.reset(self._token)
         self._tracer._record(SpanRecord(
@@ -103,20 +165,28 @@ class Tracer:
     ``span()`` reads it per call, so benches can turn tracing on after the
     session is built."""
 
-    def __init__(self, enabled: bool = False, max_spans: int = 16384):
+    def __init__(self, enabled: bool = False, max_spans: int = 16384,
+                 on_drop: Optional[Callable[[], None]] = None):
         self.enabled = bool(enabled)
         self.epoch = time.monotonic()
         self.spans: deque = deque(maxlen=int(max_spans))
         self._lock = threading.Lock()
+        # called once per span the full ring evicts
+        self._on_drop = on_drop
 
     def span(self, name: str, **args: Any):
+        """A span named ``name``; args that are None are left out."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, args)
+        return _Span(self, name,
+                     {k: v for k, v in args.items() if v is not None})
 
     def _record(self, rec: SpanRecord) -> None:
         with self._lock:
+            full = len(self.spans) == self.spans.maxlen
             self.spans.append(rec)
+        if full and self._on_drop is not None:
+            self._on_drop()
 
     def clear(self) -> None:
         with self._lock:

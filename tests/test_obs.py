@@ -272,13 +272,21 @@ def test_kernel_fallback_scoped_per_session():
     assert a.kernel_fallbacks() == 2 and b.kernel_fallbacks() == 1
 
 
-def test_kernel_fallback_module_shim_still_monotonic():
+def test_kernel_fallback_outside_a_session_logs_and_counts_nowhere(caplog):
+    import logging
+
     from repro.core import delta as delta_mod
-    before = delta_mod._kernel_fallbacks
-    with SessionObs().activate():
-        delta_mod.note_kernel_fallback("shim", RuntimeError("no kernel"))
-    # the deprecated module-global keeps counting even when scoped
-    assert delta_mod._kernel_fallbacks == before + 1
+    a = SessionObs()
+    with caplog.at_level(logging.WARNING, logger="repro.core.delta"):
+        delta_mod.note_kernel_fallback("loose", RuntimeError("no kernel"))
+    logged = [r for r in caplog.records if "device kernel" in r.message]
+    assert len(logged) == 1 and "loose" in logged[0].getMessage()
+    assert delta_mod.kernel_fallbacks() == 0
+    with a.activate():
+        delta_mod.note_kernel_fallback("inside", RuntimeError("no kernel"))
+        assert delta_mod.kernel_fallbacks() == 1
+    assert a.kernel_fallbacks() == 1
+    assert SessionObs().kernel_fallbacks() == 0
 
 
 # ---------------------------------------------------------------------------

@@ -258,9 +258,10 @@ def test_fetch_patch_chunks_fallback_routes_through_counter(tmp_path,
     assert victim is not None
     del store.chunks[victim]
 
-    fb0 = delta_mod._kernel_fallbacks
+    fb0 = sess.obs.kernel_fallbacks()
     st = sess.checkout(cid)                  # must still restore (recompute
-    assert delta_mod._kernel_fallbacks > fb0  # or full load), and count
+    assert sess.obs.kernel_fallbacks() > fb0  # or full load), and count
+    assert st.kernel_fallbacks == sess.obs.kernel_fallbacks() - fb0
     want = np.arange(1 << 14, dtype=np.int32) % 89
     want[np.arange(3) * 1024] = 3
     assert np.array_equal(np.asarray(sess.ns["v"]), want)
@@ -281,8 +282,8 @@ def test_checkout_scatter_kernel_error_propagates(monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("scatter kernel failed")
     monkeypatch.setattr(scatter_ops, "scatter_chunks", boom)
-    fb0 = delta_mod._kernel_fallbacks
+    fb0 = sess.obs.kernel_fallbacks()
     with pytest.raises(RuntimeError, match="scatter kernel failed"):
         sess.checkout(cid)
-    assert delta_mod._kernel_fallbacks == fb0
+    assert sess.obs.kernel_fallbacks() == fb0
     sess.close()

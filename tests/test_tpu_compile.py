@@ -8,6 +8,7 @@ accepts their block shapes, stores and VMEM use — which interpret mode
 cannot — without a chip.  Nothing runs: only shapes are passed.
 """
 import os
+import re
 
 import pytest
 
@@ -48,11 +49,15 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args, **static):
+def _compile(fn, *args, kernel, **static):
     """Compile ``fn`` for the described chip; the HLO must hold the Pallas
-    kernel (a Mosaic custom call), not an XLA stand-in."""
+    kernel (a Mosaic custom call), not an XLA stand-in, as an instruction
+    named ``kernel``: the profiler trace names its operations so, and the
+    benchmark's roofline readers find them by that name."""
     compiled = fn.lower(*args, **static).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text)
     return compiled
 
 
@@ -64,7 +69,8 @@ def test_chunk_hash_compiles(one_chip, chunk_bytes):
 
     # a bf16 MLP weight of smollm-360m stacked over its 32 layers
     x = _spec((32, 960, 2560), jnp.bfloat16, one_chip)
-    _compile(chunk_hash, x, chunk_bytes=chunk_bytes, backend="pallas")
+    _compile(chunk_hash, x, chunk_bytes=chunk_bytes, backend="pallas",
+             kernel="chunk_hash_pallas")
 
 
 @pytest.mark.parametrize("chunk_bytes", CHUNKS)
@@ -80,7 +86,7 @@ def test_delta_pack_compiles(one_chip, chunk_bytes):
         delta_pack_pallas,
         _spec((n, rows, LANES), jnp.uint32, one_chip),
         _spec((n, 2), jnp.uint32, one_chip),
-        _spec((n,), jnp.int32, one_chip))
+        _spec((n,), jnp.int32, one_chip), kernel="delta_pack_pallas")
     # the compacted buffer lives in HBM: the program's output holds it
     assert compiled.memory_analysis().output_size_in_bytes >= SEG_BYTES
 
@@ -94,7 +100,8 @@ def test_codec_encode_compiles(one_chip, chunk_bytes):
 
     w = chunk_bytes // 4
     rows = _spec((SEG_BYTES // chunk_bytes, w), jnp.uint32, one_chip)
-    _compile(codec_encode_pallas, rows, gw=group_words_for(w))
+    _compile(codec_encode_pallas, rows, gw=group_words_for(w),
+             kernel="codec_encode_pallas")
 
 
 @pytest.mark.parametrize("chunk_bytes", CHUNKS)
@@ -109,7 +116,8 @@ def test_patch_scatter_compiles(one_chip, chunk_bytes):
     _compile(patch_scatter_pallas,
              _spec((n_chunks, w), jnp.uint32, one_chip),
              _spec((k,), jnp.int32, one_chip),
-             _spec((k, w), jnp.uint32, one_chip))
+             _spec((k, w), jnp.uint32, one_chip),
+             kernel="patch_scatter_pallas")
 
 
 @pytest.mark.parametrize("chunk_bytes", CHUNKS)
@@ -119,4 +127,5 @@ def test_block_diff_compiles(one_chip, chunk_bytes):
     from repro.kernels.block_diff.ops import block_diff
 
     x = _spec((960, 2560), jnp.float32, one_chip)
-    _compile(block_diff, x, x, chunk_bytes=chunk_bytes, backend="pallas")
+    _compile(block_diff, x, x, chunk_bytes=chunk_bytes, backend="pallas",
+             kernel="block_diff_pallas")
