@@ -21,6 +21,10 @@ with the session's own 64 KiB chunks.  The cells, in order:
   checkout_codec   forward to the sparse cell: a full load that reads the
                    chunks the codec encoded on device
 
+Before the cells, the 16- and 8-bit word packing (``_to_words``) of a
+bf16 and an int8 device array must equal their host bytes as
+little-endian 32-bit words.
+
 Every restored leaf must equal, bit for bit, a host copy taken right after
 the cell that produced it; the kernels must have run as Pallas kernels;
 no kernel fallback may be counted; and the pack, the codec and the scatter
@@ -145,6 +149,32 @@ def reset_layer_moments(ns, layer: int) -> None:
             ns[name] = ns[name].at[layer].set(0)
 
 
+def word_packing(seed: int) -> List[str]:
+    """The 32-bit words the device packs from 16- and 8-bit arrays (every
+    bit pattern drawn, odd lengths padded) against the host's bytes viewed
+    as little-endian words; returns what differed."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.chunk_hash.ops import _to_words
+
+    failed: List[str] = []
+    rng = np.random.default_rng(seed)
+    for dtype, bits, shape in ((jnp.bfloat16, np.uint16, (4096, 1536)),
+                               (jnp.int8, np.uint8, (4097, 3))):
+        host = rng.integers(0, np.iinfo(bits).max + 1, shape,
+                            dtype=bits).view(dtype)
+        got = np.asarray(jax.jit(_to_words)(jax.device_put(host)))
+        raw = host.tobytes()
+        want = np.frombuffer(raw + b"\0" * (-len(raw) % 4), "<u4")
+        differ = int(np.count_nonzero(got != want))
+        log(f"word packing {np.dtype(dtype).name}{list(shape)}: {differ} of "
+            f"{want.size} words differ from the host bytes")
+        if differ:
+            failed.append(f"word packing of {np.dtype(dtype).name} differs")
+    return failed
+
+
 def main_path(cfg, phase: Callable, store_dir: str, seed: int, *,
               backend: str = "pallas", chunk_bytes: int = CHUNK_BYTES,
               layer: int = RESET_LAYER) -> List[str]:
@@ -154,7 +184,7 @@ def main_path(cfg, phase: Callable, store_dir: str, seed: int, *,
     from repro.optim.adamw import AdamWConfig
     from repro.train.loop import ManagedTrainingSession
 
-    failed: List[str] = []
+    failed = word_packing(seed)
     sess = ManagedTrainingSession(cfg, AdamWConfig(), open_store(
         f"dir://{store_dir}"), chunk_bytes=chunk_bytes)
     kishu = sess.kishu

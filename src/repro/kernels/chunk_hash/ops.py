@@ -18,15 +18,33 @@ import numpy as np
 from repro.core import hashing
 from repro.kernels.chunk_hash.kernel import chunk_hash_pallas
 from repro.kernels.chunk_hash.ref import chunk_hash_ref
+from repro.kernels.common import LANES
 from repro.kernels.delta_codec.host import pow2ceil
+
+
+def _lane_selector(per_word: int) -> np.ndarray:
+    """0/1 [L, L] matrix, L = 128 * per_word, whose column k * 128 + j
+    picks item per_word * j + k of a row: it moves the k-th item of every
+    word into the k-th block of 128 lanes."""
+    n = LANES * per_word
+    src = np.arange(n)
+    sel = np.zeros((n, n), np.float32)
+    sel[src, (src % per_word) * LANES + src // per_word] = 1
+    return sel
 
 
 def _to_words(x: jax.Array) -> jax.Array:
     """Flatten + bitcast any-dtype array to uint32 words (little-endian).
 
-    Narrow items are packed from strided slices of the flat array: an
-    intermediate [N, 2] or [N, 4] array would be padded to 128 lanes in
-    TPU memory (64x its size)."""
+    Narrow items are packed in lane-dense rows of 128 words: each byte of
+    an item is a bf16 plane (0..255, exact), one 0/1 selection matmul
+    (exact: one 1.0 x byte product per output) moves the k-th item of every
+    word into the k-th block of 128 lanes, and shifts OR the blocks into
+    words.  Strided slices of the flat array compile to gathers on a TPU,
+    and an [N, 2] or [N, 4] intermediate is padded to 128 lanes (64x its
+    size).  On a TPU, XLA's bitcast of bf16 / f16 data itself flushes
+    denormals to zero and rewrites NaNs, so those items' bits are not kept
+    there."""
     flat = x.reshape(-1)
     item = np.dtype(x.dtype).itemsize
     if item == 4:
@@ -38,14 +56,20 @@ def _to_words(x: jax.Array) -> jax.Array:
         raise TypeError(f"unsupported itemsize {item} for dtype {x.dtype}")
     per_word = 4 // item
     u = jax.lax.bitcast_convert_type(flat, jnp.uint16 if item == 2
-                                     else jnp.uint8)
-    pad = (-u.shape[0]) % per_word
-    if pad:
-        u = jnp.concatenate([u, jnp.zeros((pad,), u.dtype)])
-    words = u[0::per_word].astype(jnp.uint32)
-    for k in range(1, per_word):
-        words = words | (u[k::per_word].astype(jnp.uint32) << (8 * item * k))
-    return words
+                                     else jnp.uint8).astype(jnp.uint32)
+    n = u.shape[0]
+    row = LANES * per_word
+    u = jnp.pad(u, (0, -n % row)).reshape(-1, row)
+    sel = jnp.asarray(_lane_selector(per_word), jnp.bfloat16)
+    words = jnp.zeros((u.shape[0], LANES), jnp.uint32)
+    for b in range(item):
+        plane = ((u >> (8 * b)) & 0xFF).astype(jnp.bfloat16)
+        lanes = jnp.dot(plane, sel, preferred_element_type=jnp.float32
+                        ).astype(jnp.uint32)
+        for k in range(per_word):
+            words = words | (lanes[:, k * LANES:(k + 1) * LANES]
+                             << (8 * (item * k + b)))
+    return words.reshape(-1)[:-(-n // per_word)]
 
 
 @functools.lru_cache(maxsize=None)
@@ -59,9 +83,11 @@ def words_supported(dtype) -> bool:
     return True
 
 
+@functools.partial(jax.jit, static_argnames=("chunk_bytes",))
 def chunk_rows(x: jax.Array, chunk_bytes: int) -> jax.Array:
     """uint32 [n_chunks, chunk_bytes // 4] word rows of ``x``, the tail
-    zero-padded (n_chunks >= 1)."""
+    zero-padded (n_chunks >= 1): one compiled program per shape, dtype and
+    chunk width, inlined where a jitted caller traces it."""
     wpc = chunk_bytes // 4
     words = _to_words(x)
     n_chunks = max(-(-words.shape[0] // wpc), 1)

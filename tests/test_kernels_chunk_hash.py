@@ -45,6 +45,36 @@ def test_bfloat16():
     assert np.array_equal(got, want)
 
 
+SUBWORD = [jnp.bfloat16, np.float16, np.int16, np.uint16, np.int8, np.uint8]
+# NaN payloads, +-0, +-inf, denormals, all-ones (bf16 / fp16 bit patterns)
+EDGE_BITS = {2: [0x7FC1, 0xFFC3, 0x7E01, 0x0000, 0x8000, 0x7F80, 0xFF80,
+                 0x7C00, 0xFC00, 0x7E00, 0x0001, 0x8001, 0x03FF, 0xFFFF],
+             1: [0x00, 0x80, 0x7F, 0x01, 0xFF]}
+
+
+@pytest.mark.parametrize("dtype", SUBWORD)
+@pytest.mark.parametrize("shape", [(1,), (255,), (256,), (257,), (511,),
+                                   (4097,), (3, 1000)])
+def test_to_words_subword_matches_host_bytes(dtype, shape):
+    """16- and 8-bit items packed into words equal the host bytes, zero
+    padded to a whole word, viewed as little-endian uint32."""
+    from repro.kernels.chunk_hash.ops import _to_words
+
+    item = np.dtype(dtype).itemsize
+    ubits = np.uint16 if item == 2 else np.uint8
+    rng = np.random.default_rng(item * 1000 + len(shape) * 100 + shape[-1])
+    bits = rng.integers(0, 1 << (8 * item), shape).astype(ubits)
+    flat = bits.reshape(-1)
+    edge = np.asarray(EDGE_BITS[item], ubits)
+    flat[:edge.size] = edge[:flat.size]
+    x = bits.view(np.dtype(dtype))
+    raw = x.tobytes()
+    want = np.frombuffer(raw + b"\0" * (-len(raw) % 4), "<u4")
+    got = np.asarray(jax.jit(_to_words)(jnp.asarray(x)))
+    assert got.dtype == np.uint32
+    assert np.array_equal(got, want)
+
+
 def test_kernel_direct_prechunked():
     words = jnp.asarray(
         np.random.default_rng(0).integers(0, 2**32, (8, 1024), dtype=np.uint32))

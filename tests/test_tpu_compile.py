@@ -129,3 +129,27 @@ def test_block_diff_compiles(one_chip, chunk_bytes):
     x = _spec((960, 2560), jnp.float32, one_chip)
     _compile(block_diff, x, x, chunk_bytes=chunk_bytes, backend="pallas",
              kernel="block_diff_pallas")
+
+
+@pytest.mark.parametrize("chunk_bytes", CHUNKS)
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_pack_words_compiles_without_gather(one_chip, dtype, chunk_bytes):
+    """The word packing of 16- and 8-bit leaves before the delta pass is
+    lane-dense: a gather in its HLO is the strided packing that took
+    seconds a commit on the chip.  The program keeps its name, which the
+    benchmark's ``word_pack_ms`` reads from the trace."""
+    import jax.numpy as jnp
+
+    from repro.kernels.common import TILE_WORDS
+    from repro.kernels.delta_pack.ops import _pack_words
+
+    x = _spec((4096, 1536), jnp.dtype(dtype), one_chip)
+    lowered = _pack_words.lower(x, chunk_bytes=chunk_bytes, tiled=True)
+    text = lowered.compile().as_text()
+    assert re.search(r"^HloModule jit__pack_words\b", text, re.M)
+    # no gather instruction, nor the index checks XLA adds for one
+    assert not re.search(r"\bgather\(|Gather", text)
+    n_words = 4096 * 1536 * jnp.dtype(dtype).itemsize // 4
+    rows = max(chunk_bytes // 4, TILE_WORDS) // 128
+    assert lowered.out_info.shape == (-(-n_words // (chunk_bytes // 4)),
+                                      rows, 128)
