@@ -26,12 +26,12 @@ import numpy as np
 
 from repro import obs
 from repro.core import delta as delta_mod
-from repro.core import hashing
+from repro.core import hashing, parallel
 from repro.core.chunkstore import ChunkCache, ChunkStore, chunk_key
 from repro.core.covariable import CovKey, LeafRecord
 from repro.core.graph import key_str
-from repro.core.serialize import (SerializationError, base_of, leaf_meta,
-                                  leaf_nbytes, leaf_to_bytes, view_spec)
+from repro.core.serialize import (SerializationError, base_of, leaf_buffer,
+                                  leaf_meta, leaf_nbytes, view_spec)
 
 
 @dataclass
@@ -80,7 +80,7 @@ def _pack_usable(pack, det_hex: List[str], dirty_set, n: int,
 
 class _Stopwatch:
     """``fn``, timed: the seconds spent in it summed over its calls (a
-    manifest span's per-chunk key hashing and dedup lookups)."""
+    manifest span's dedup lookups)."""
     __slots__ = ("fn", "seconds", "calls")
 
     def __init__(self, fn: Callable):
@@ -95,6 +95,56 @@ class _Stopwatch:
         finally:
             self.seconds += time.perf_counter() - t0
             self.calls += 1
+
+
+# A leaf with at least this many chunks to key keys them on the shared I/O
+# pool, at most KEY_SLAB_CHUNKS to a task.  blake2b releases the GIL, so
+# keying is CPU work that spreads over the host's cores; below the
+# threshold a task's dispatch costs more than the hashing it takes off the
+# commit thread.
+KEY_POOL_MIN_CHUNKS = 16
+KEY_SLAB_CHUNKS = 64
+
+
+class _Keys:
+    """Content keys of one co-variable's chunks.  ``seconds`` is the wall
+    time the commit thread spent computing or awaiting them, ``calls`` the
+    chunks keyed, ``pooled`` those keyed on the I/O pool."""
+    __slots__ = ("seconds", "calls", "pooled")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.calls = 0
+        self.pooled = 0
+
+    def __call__(self, data) -> str:
+        """One chunk's key, on this thread."""
+        t0 = time.perf_counter()
+        try:
+            return chunk_key(data)
+        finally:
+            self.seconds += time.perf_counter() - t0
+            self.calls += 1
+
+    def of_spans(self, buf, spans: List[Tuple[int, int]]) -> List[str]:
+        """Keys of ``buf[lo:hi]`` for each span, in order: on the pool when
+        there are enough of them and this thread is not a pool worker."""
+        t0 = time.perf_counter()
+        workers = parallel.resolve_io_threads()
+        if len(spans) >= KEY_POOL_MIN_CHUNKS and workers > 1 \
+                and not parallel.in_io_worker():
+            slab = parallel.slab_size_for(len(spans), workers,
+                                          max_slab=KEY_SLAB_CHUNKS)
+            keys = [ck for part in parallel.map_parallel(
+                        lambda s: [chunk_key(buf[lo:hi]) for lo, hi in s],
+                        list(parallel.iter_slabs(spans, slab)), workers)
+                    for ck in part]
+            self.pooled += len(spans)
+        else:
+            keys = [chunk_key(buf[lo:hi]) for lo, hi in spans]
+        self.seconds += time.perf_counter() - t0
+        self.calls += len(spans)
+        return keys
 
 
 def _try_delta_manifest(base, det_hex: List[str], prev_manifest,
@@ -221,21 +271,27 @@ def build_manifest(store: ChunkStore, key: CovKey,
     ``delta_ranges=False`` disables the dirty-range fast path (benchmark
     baseline — the pre-delta cov-granular writer).
 
-    Traced, it is one ``manifest`` span per co-variable whose args sum the
-    time of the per-chunk key hashing (``keys_s``) and dedup lookups
-    (``has_s``) over its ``chunks`` keyed chunks."""
+    Traced, it is one ``manifest`` span per co-variable whose args give the
+    wall time the commit thread spent on its ``chunks`` content keys
+    (``keys_s``; ``keyed_pool`` of them keyed on the I/O pool) and the
+    summed time of its dedup lookups (``has_s``).  The counter
+    ``kishu_chunks_keyed_total{mode=pool|serial}`` counts the keyed chunks
+    once per co-variable."""
     if has is None:
         has = store.has_chunk
     with obs.span("manifest", key=key_str(key)) as sp:
         timed = sp is not obs.NULL_SPAN
-        keys = _Stopwatch(chunk_key) if timed else chunk_key
+        keys = _Keys()
         lookups = _Stopwatch(has) if timed else has
         man = _build_manifest(records, ns, chunk_bytes, prev_manifest, stats,
                               put, lookups, delta_ranges, packs, put_stored,
                               keys)
+        if keys.calls:
+            obs.count("kishu_chunks_keyed_total", keys.calls,
+                      mode="pool" if keys.pooled else "serial")
         if timed:
             sp.set(keys_s=keys.seconds, has_s=lookups.seconds,
-                   chunks=keys.calls)
+                   chunks=keys.calls, keyed_pool=keys.pooled)
         return man
 
 
@@ -267,7 +323,7 @@ def _build_manifest(records: List[LeafRecord], ns,
             return man
 
     try:
-        blob, meta = leaf_to_bytes(base)
+        buf, meta = leaf_buffer(base)
     except SerializationError:
         stats.unserializable += 1
         return {"members": members, "unserializable": True}
@@ -280,24 +336,30 @@ def _build_manifest(records: List[LeafRecord], ns,
             if i < len(prev_det):
                 prev_chunks[i] = {"det": prev_det[i], **c}
 
-    chunks = []
-    n = len(blob)
-    n_chunks = max(-(-n // chunk_bytes), 1) if n else 0
+    buf = memoryview(buf)       # a pickle's chunks are views too
+    n = len(buf)
+    spans = [(lo, min(lo + chunk_bytes, n)) for lo in range(0, n, chunk_bytes)]
     stats.bytes_serialized += n
     stats.bytes_logical += n
-    for i in range(n_chunks):
-        lo, hi = i * chunk_bytes, min((i + 1) * chunk_bytes, n)
-        prev = prev_chunks.get(i)
-        if prev is not None and i < len(det_hex) and prev["det"] == det_hex[i]:
-            # unchanged chunk: reference previous storage, no hashing/copy
+    # unchanged chunks reference the previous storage, unhashed and uncopied
+    fresh = [i for i in range(len(spans))
+             if i not in prev_chunks or i >= len(det_hex)
+             or prev_chunks[i]["det"] != det_hex[i]]
+    keys = dict(zip(fresh, key_fn.of_spans(buf, [spans[i] for i in fresh])))
+    chunks = []
+    for i, (lo, hi) in enumerate(spans):
+        ck = keys.get(i)
+        if ck is None:
+            prev = prev_chunks[i]
             chunks.append({"key": prev["key"], "n": prev["n"]})
             stats.chunks_reused += 1
             continue
-        data = blob[lo:hi]
-        ck = key_fn(data)
         if has(ck):
             stats.chunks_dedup += 1
         else:
+            # the chunk's one copy, shared by the cache and the put batch:
+            # a later change to a host leaf's buffer cannot reach it
+            data = bytes(buf[lo:hi])
             put(ck, data)
             stats.chunks_written += 1
             stats.bytes_written += len(data)
@@ -439,8 +501,9 @@ class CheckpointWriter:
                 self._flush_batch()      # bound buffered delta memory
 
     def _put(self, ck: str, data: bytes) -> None:
+        data = bytes(data)      # one copy of a view, shared by cache and batch
         if self.cache is not None:
-            self.cache.put(ck, bytes(data))
+            self.cache.put(ck, data)
         self._enqueue(ck, data, stored=False)
 
     def _put_stored(self, ck: str, logical: bytes, frame: bytes) -> None:

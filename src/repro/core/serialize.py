@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import pickle
 from dataclasses import dataclass, field
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -116,22 +116,32 @@ def _to_device(arr: np.ndarray) -> jax.Array:
     return out
 
 
-def leaf_to_bytes(x: Any) -> Tuple[bytes, dict]:
-    """Serialize a *base* leaf. Raises SerializationError for opaque leaves."""
+def leaf_buffer(x: Any) -> Tuple[Union[memoryview, bytes], dict]:
+    """Serialize a *base* leaf without copying its bytes: an array or PRNG
+    key is a flat byte view of its C-order host copy (a numpy leaf's own
+    buffer, so a chunk kept past the call must be copied out), anything
+    else pickled.  Raises SerializationError for opaque leaves."""
     meta = leaf_meta(x)
-    if meta["kind"] == "prng":
-        return _to_host(jax.random.key_data(x)).tobytes(), meta
-    if meta["kind"] == "array":
-        arr = _to_host(x)
+    if meta["kind"] in ("prng", "array"):
+        arr = _to_host(jax.random.key_data(x) if meta["kind"] == "prng"
+                       else x)
         if not arr.flags["C_CONTIGUOUS"]:
             arr = np.ascontiguousarray(arr)
-        return arr.tobytes(), meta
+        if arr.dtype.hasobject:         # no byte view of object pointers
+            return arr.tobytes(), meta
+        return memoryview(arr.reshape(-1).view(np.uint8)), meta
     if isinstance(x, OpaqueLeaf):
         raise SerializationError(f"OpaqueLeaf({x.note})")
     try:
         return pickle.dumps(x), meta
     except Exception as e:  # noqa: BLE001 — any pickling failure is EAFP
         raise SerializationError(str(e)) from e
+
+
+def leaf_to_bytes(x: Any) -> Tuple[bytes, dict]:
+    """Serialize a *base* leaf. Raises SerializationError for opaque leaves."""
+    buf, meta = leaf_buffer(x)
+    return bytes(buf), meta
 
 
 def leaf_from_bytes(data: bytes, meta: dict, *, device_put: bool = True) -> Any:

@@ -313,6 +313,7 @@ def test_probe_engages_pipeline_on_slow_store(monkeypatch):
     s.loader.probe_threshold_s = 1e-3    # default adaptive threshold
     c1 = s.run("step", seed=1)
     s.run("step", seed=2)
+    calls.clear()                        # the commits key on the pool
     s.checkout(c1)
     assert calls                         # 5ms/chunk >> threshold: parallel
 
@@ -325,6 +326,7 @@ def test_probe_stays_serial_on_fast_store(monkeypatch):
     s.loader.probe_threshold_s = float("inf")     # force bandwidth-bound
     c1 = s.run("step", seed=1)
     s.run("step", seed=2)
+    calls.clear()                        # the commits key on the pool
     st = s.checkout(c1)
     assert not calls                     # degraded to serial slab loop
     assert st.covs_loaded == N_VARS      # ...and still restored everything
