@@ -1,5 +1,5 @@
 """Baselines from the paper's evaluation (§7.1), reimplemented on the same
-storage substrate for apples-to-apples benchmarks:
+storage substrate so that they compare like for like:
 
 - :class:`DumpSession`     — application-level whole-state serialization
   (dill.dump_session / ForkIt analogue): one blob per commit, checkout loads

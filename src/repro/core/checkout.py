@@ -186,10 +186,7 @@ class StateLoader:
         # shared chunk cache (writer-populated): just-committed chunks are
         # served from memory, never the backend
         self.chunk_cache = cache
-        # chunk-level patch checkout (dirty-chunk fetch into live buffers);
-        # False restores the cov-granular pre-delta path (benchmarks).
-        self.patch_enabled = True
-        # <=1 forces the serial pre-engine path (benchmark baseline).
+        # <=1 fetches serially on the calling thread.
         self.io_threads = parallel.resolve_io_threads(io_threads)
         # Adaptive engagement (see parallel.py): first-slab latency below
         # the gate stays serial outright; above it a measured trial decides.
@@ -494,8 +491,6 @@ class StateLoader:
         loads; patches are also recorded on ``plan.patches``."""
         full: List[Tuple[CovKey, str]] = []
         patches: List[ChunkPatch] = []
-        if not self.patch_enabled:
-            return [], sorted(plan.to_load.items())
         alias_groups: Dict[int, set] = {}
         for name, rec in records.items():
             alias_groups.setdefault(rec.alias_id, set()).add(name)

@@ -219,17 +219,13 @@ def _try_delta_manifest(base, det_hex: List[str], prev_manifest,
         # async) writer while the reader keeps the *next* segment's
         # device→host DMA in flight (DESIGN.md §15).  With the on-device
         # codec engaged the rows cross PCIe as bit-plane frames and are
-        # stored as-is (put_stored); keys stay logical-byte either way.
+        # stored as-is (put_stored; without one, as logical bytes); keys
+        # stay logical-byte either way.
         stats.covs_packed += 1
         enc0, skip0 = pack.codec_chunks_encoded, pack.codec_chunks_skipped
-        if put_stored is not None:
-            for i, cdata, frame in pack.read_chunks_encoded(dirty):
-                stats.bytes_serialized += len(cdata)
-                _store(i, cdata, frame)
-        else:
-            for i, cdata in pack.read_chunks(dirty):
-                stats.bytes_serialized += len(cdata)
-                _store(i, cdata)
+        for i, cdata, frame in pack.read_chunks_encoded(dirty):
+            stats.bytes_serialized += len(cdata)
+            _store(i, cdata, frame)
         stats.chunks_encoded += pack.codec_chunks_encoded - enc0
         stats.chunks_codec_skipped += pack.codec_chunks_skipped - skip0
     else:
@@ -259,7 +255,6 @@ def build_manifest(store: ChunkStore, key: CovKey,
                    stats: WriteStats,
                    put: Callable[[str, bytes], None],
                    has: Optional[Callable[[str], bool]] = None,
-                   delta_ranges: bool = True,
                    packs: Optional[Dict[int, Any]] = None,
                    put_stored: Optional[Callable[[str, bytes, bytes],
                                                  None]] = None) -> dict:
@@ -268,8 +263,9 @@ def build_manifest(store: ChunkStore, key: CovKey,
     ``has`` is the CAS-dedup membership test; the writer passes a variant
     that also sees chunks batched/enqueued but not yet landed in the store,
     so deferred (batched or async) puts never double-write within a delta.
-    ``delta_ranges=False`` disables the dirty-range fast path (benchmark
-    baseline — the pre-delta cov-granular writer).
+    ``put_stored`` stores a chunk the device codec framed (frame to the
+    backend, logical bytes to the cache); without it every chunk is stored
+    as its logical bytes through ``put``.
 
     Traced, it is one ``manifest`` span per co-variable whose args give the
     wall time the commit thread spent on its ``chunks`` content keys
@@ -284,8 +280,7 @@ def build_manifest(store: ChunkStore, key: CovKey,
         keys = _Keys()
         lookups = _Stopwatch(has) if timed else has
         man = _build_manifest(records, ns, chunk_bytes, prev_manifest, stats,
-                              put, lookups, delta_ranges, packs, put_stored,
-                              keys)
+                              put, lookups, packs, put_stored, keys)
         if keys.calls:
             obs.count("kishu_chunks_keyed_total", keys.calls,
                       mode="pool" if keys.pooled else "serial")
@@ -297,8 +292,8 @@ def build_manifest(store: ChunkStore, key: CovKey,
 
 def _build_manifest(records: List[LeafRecord], ns,
                     chunk_bytes: int, prev_manifest: Optional[dict],
-                    stats: WriteStats, put, has, delta_ranges: bool,
-                    packs, put_stored, key_fn) -> dict:
+                    stats: WriteStats, put, has, packs, put_stored,
+                    key_fn) -> dict:
     members = []
     for r in records:
         members.append({"name": r.name, "kind": r.kind, "dtype": r.dtype,
@@ -314,13 +309,12 @@ def _build_manifest(records: List[LeafRecord], ns,
 
     # chunk-granular fast path: det-hash compare first, then serialize /
     # transfer only the dirty ranges (bytes_serialized ~ dirty bytes)
-    if delta_ranges:
-        man = _try_delta_manifest(base, det_hex, prev_manifest, chunk_bytes,
-                                  stats, put, has, members,
-                                  pack=(packs or {}).get(id(base)),
-                                  put_stored=put_stored, key_fn=key_fn)
-        if man is not None:
-            return man
+    man = _try_delta_manifest(base, det_hex, prev_manifest, chunk_bytes,
+                              stats, put, has, members,
+                              pack=(packs or {}).get(id(base)),
+                              put_stored=put_stored, key_fn=key_fn)
+    if man is not None:
+        return man
 
     try:
         buf, meta = leaf_buffer(base)
@@ -392,9 +386,6 @@ class CheckpointWriter:
         self.async_write = async_write
         self.write_deadline_s = write_deadline_s
         self.drain_batch = drain_batch
-        # dirty-range serialization; False = pre-delta full-blob writer
-        # (benchmark baseline)
-        self.delta_ranges = True
         # WAL hook (txn.TxnEngine.journal_chunks): called with a batch's
         # keys immediately before the backend put, so a crashed commit's
         # chunks are journaled and recovery can roll them back exactly
@@ -570,7 +561,6 @@ class CheckpointWriter:
                 man = build_manifest(self.store, key, records, ns,
                                      self.chunk_bytes, prev_manifest_of(key),
                                      stats, self._put, self._has,
-                                     delta_ranges=self.delta_ranges,
                                      packs=packs,
                                      put_stored=self._put_stored)
                 manifests[key_str(key)] = man

@@ -7,7 +7,7 @@ dedup for free — the storage-level core of Kishu's "small incremental
 checkpoints" result, plus our beyond-paper chunk-level dedup (DESIGN.md §2).
 
 Backends:
-  - MemoryStore     — dicts (benchmark baseline for pure algorithm cost)
+  - MemoryStore     — dicts (tests; pure algorithm cost)
   - DirectoryStore  — one file per chunk, sharded dirs; shard-local writers
                       on a multi-host cluster never contend (DESIGN.md §8)
   - SQLiteStore     — single-file deployment, as the paper ships (§6.1)
@@ -807,7 +807,7 @@ class CompressedStore(ChunkStore):
     put path; reads pass through (all backends decode frames natively), so
     compressed and uncompressed chunks mix freely in one store and either
     reader works against either writer.  Tracks logical vs stored bytes so
-    benchmarks and the CLI can report the compression win."""
+    the CLI can report the compression win."""
 
     def __init__(self, inner: ChunkStore, codec="auto"):
         self.inner = inner

@@ -130,8 +130,7 @@ def scatter_parallel(fn: Callable[[Any], Any], items: Sequence[Any]
 def resolve_io_threads(n: Optional[int] = None) -> int:
     """Effective worker count: explicit arg > $KISHU_IO_THREADS > default.
 
-    ``<= 1`` means serial (the pre-engine behavior, kept as the benchmark
-    baseline and the fallback for tiny transfers)."""
+    ``<= 1`` means serial (the behavior for tiny transfers too)."""
     if n is None:
         env = os.environ.get("KISHU_IO_THREADS", "").strip()
         try:

@@ -7,7 +7,7 @@ primary mechanism in the TPU adaptation):
 - ``block_diff``: exact per-chunk dirty-compare when both versions are
   device-resident (undo fast path).
 
-Beyond-paper (perf hillclimb, EXPERIMENTS.md §Perf cell A):
+Beyond-paper:
 
 - ``flash_attention``: tiled online-softmax attention (forward/prefill) —
   removes the S²-logit HBM traffic that dominates the roofline memory term

@@ -18,8 +18,7 @@ compacted rows in HBM, so VMEM use does not grow with the segment.
 
 Traffic accounting: ``bytes_transferred`` counts every byte this pack moved
 device→host — 12 bytes/chunk of metadata (8 hash + 4 dirty flag) plus the
-compacted rows actually materialized — the numerator of the detection
-roofline in benchmarks/bench_device_delta.py.
+compacted rows actually materialized.
 """
 from __future__ import annotations
 

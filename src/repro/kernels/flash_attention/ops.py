@@ -3,7 +3,7 @@
 Handles layout ([B,S,H,hd] <-> [B*H,S,hd]), GQA head-group mapping via the
 kernel's K/V index maps (no materialized repeat), and the ref dispatch.
 Forward-only: the training path keeps XLA attention (a Pallas backward is
-future work; see EXPERIMENTS.md §Perf kernel note).
+future work).
 """
 from __future__ import annotations
 

@@ -12,8 +12,8 @@ For each cell this driver:
   3. ``jit(step).lower(...).compile()`` — proving the distribution config is
      coherent (sharding divisibility, collective legality, memory layout),
   4. records ``memory_analysis()`` / ``cost_analysis()`` and the collective
-     byte volume parsed from the optimized HLO into a JSON artifact that
-     benchmarks/roofline.py consumes.
+     byte volume parsed from the optimized HLO into a JSON artifact under
+     ``--out`` (default ``artifacts/dryrun`` in the working directory).
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-1.7b --shape train_4k --mesh single
@@ -37,8 +37,7 @@ from repro.sharding.rules import ShardingRules
 from repro.optim.adamw import AdamWConfig
 from repro.train import step as step_lib
 
-ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                       "benchmarks", "artifacts", "dryrun")
+ART_DIR = os.path.join("artifacts", "dryrun")
 
 DTYPE_BYTES = {
     "f64": 8, "u64": 8, "s64": 8, "c64": 8, "f32": 4, "u32": 4, "s32": 4,

@@ -32,8 +32,3 @@ def make_local_mesh(model: int = 1, data: int = 0):
     data = data or (n // model)
     return _auto_mesh((data, model), ("data", "model"))
 
-
-# Hardware constants for the roofline model (TPU v5e per chip).
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW_PER_LINK = 50e9          # bytes/s/link

@@ -1,4 +1,4 @@
-"""Reduced-config helpers shared by smoke tests, examples and benchmarks."""
+"""Reduced-config helpers shared by smoke tests and examples."""
 from __future__ import annotations
 
 import dataclasses

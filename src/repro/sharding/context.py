@@ -6,7 +6,7 @@ DeepSeek-V3 — catastrophic, and what GSPMD picks) or all-gather the weights
 (~44 MB/device/layer — ZeRO-style, what we want).  ``moe_weight_gather``
 installs per-weight resharding constraints that moe_forward applies at use
 time, forcing the gather resolution while the *persistent* weights stay
-pod-sharded (the memory win).  Measured in EXPERIMENTS.md §Perf iteration C3.
+pod-sharded (the memory win).
 """
 from __future__ import annotations
 

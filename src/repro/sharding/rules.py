@@ -32,7 +32,7 @@ def _divides(n: int, by: int) -> bool:
 
 
 class ShardingRules:
-    """Sharding policy. Tunables (hillclimb levers, EXPERIMENTS.md §Perf):
+    """Sharding policy. Tunables:
 
     - ``fsdp_pods``: fold the pod axis into the FSDP group.
     - ``expert_pod_shard``: shard the MoE expert dim over ("pod","data")

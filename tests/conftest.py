@@ -1,5 +1,5 @@
-# NOTE: do NOT set XLA_FLAGS / device-count overrides here — smoke tests and
-# benchmarks must see the real single CPU device.  Distribution tests that
+# NOTE: do NOT set XLA_FLAGS / device-count overrides here — the smoke tests
+# must see the real single CPU device.  Distribution tests that
 # need multiple devices spawn subprocesses with their own XLA_FLAGS.
 import os
 import sys

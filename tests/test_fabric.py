@@ -417,6 +417,83 @@ def test_session_restores_with_one_replica_of_each_pair_down(fabric_session):
     assert scrub(store).problems == 0
 
 
+def _tenth_dirty_session(store):
+    """Four 16-chunk arrays; each ``mutate`` rewrites 2 chunks of each."""
+    s = KishuSession(store, chunk_bytes=1 << 12, cache_bytes=0)
+
+    def init(ns, seed):
+        rng = np.random.default_rng(seed)
+        for i in range(4):
+            ns[f"v{i}"] = rng.standard_normal(1 << 14).astype(np.float32)
+
+    def mutate(ns, seed):
+        rng = np.random.default_rng(seed)
+        for i in range(4):
+            for c in range(2):
+                ns[f"v{i}"][c * 1024] = rng.standard_normal()
+    s.register("init", init)
+    s.register("mutate", mutate)
+    s.init_state({})
+    return s
+
+
+def _snap(s):
+    return {n: np.asarray(s.ns[n]).tobytes() for n in s.ns.names()}
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_session_hops_bit_identical_over_shards(n_shards, tmp_path):
+    """Checkouts back and forth over a ring of directory stores restore
+    the same bytes as over one directory store."""
+    if n_shards == 1:
+        store = DirectoryStore(str(tmp_path / "single"))
+    else:
+        store = ShardedStore([DirectoryStore(str(tmp_path / f"s{i}"))
+                              for i in range(n_shards)])
+    s = _tenth_dirty_session(store)
+    prev = s.run("init", seed=1)
+    prev_snap = _snap(s)
+    for r in range(2):
+        cur = s.run("mutate", seed=100 + r)
+        cur_snap = _snap(s)
+        st = s.checkout(prev)
+        assert st.covs_patched == 4 and _snap(s) == prev_snap
+        s.checkout(cur)
+        assert _snap(s) == cur_snap
+        prev, prev_snap = cur, cur_snap
+    s.close()
+    if n_shards > 1:
+        assert all(sh.n_chunks() > 0 for sh in store.shards)
+
+
+@pytest.mark.parametrize("loss", ["wiped", "fault_injected"])
+def test_session_restores_through_replica_loss(loss, tmp_path):
+    """One replica of two lost (its chunks wiped, or every read failing):
+    checkout restores bit-identically from the survivor, and a repairing
+    scrub leaves a deep scrub with 0 problems."""
+    uri = f"fabric://rep(dir://{tmp_path}/r0,dir://{tmp_path}/r1)"
+    s = _tenth_dirty_session(open_store(uri))
+    c1 = s.run("init", seed=1)
+    snap1 = _snap(s)
+    s.run("mutate", seed=2)
+    s.close()
+    if loss == "wiped":
+        _wipe_chunks(str(tmp_path / "r0"))
+        store = open_store(uri)
+    else:
+        store = ReplicatedStore([
+            FaultInjectedStore(DirectoryStore(str(tmp_path / "r0")),
+                               fail_get=lambda k: True),
+            DirectoryStore(str(tmp_path / "r1"))])
+    s2 = _tenth_dirty_session(store)
+    s2.checkout(c1)
+    assert _snap(s2) == snap1
+    s2.close()
+    fresh = open_store(uri)
+    scrub(fresh, repair=True)
+    assert scrub(fresh, deep=True).problems == 0
+
+
 def test_session_falls_back_to_recompute_when_lost_everywhere(tmp_path):
     """Chunk lost on ALL replicas -> DataRestorer recomputation still
     restores the state."""

@@ -229,6 +229,44 @@ def test_session_trace_covers_pipelines_and_nests(tmp_path):
     assert doc and [r.name for r in spans_from_doc(doc["spans"])]
 
 
+@pytest.mark.parametrize("uri", ["memory://", "sqlite://{tmp}/cas.db"])
+def test_session_chrome_trace_export(uri, tmp_path):
+    """A traced commit, a partly dirty commit and a checkout export as
+    complete Chrome events naming at least 6 distinct stages, and every
+    child span lies inside its parent's interval."""
+    sess = KishuSession(open_store(uri.format(tmp=tmp_path)),
+                        chunk_bytes=1 << 12, trace=True)
+
+    def init(ns):
+        rng = np.random.default_rng(7)
+        for i in range(2):
+            ns[f"v{i}"] = rng.standard_normal(1 << 14).astype(np.float32)
+
+    def mutate(ns, seed):
+        for i in range(2):
+            for c in range(2):               # 2 of 16 chunks dirty
+                ns[f"v{i}"][c * 1024] = float(seed)
+    sess.register("init", init)
+    sess.register("mutate", mutate)
+    sess.init_state({})
+    c1 = sess.run("init")
+    sess.run("mutate", seed=1)
+    sess.checkout(c1)
+    spans = list(sess.obs.tracer.spans)
+    sess.close()
+    events = chrome_trace(spans)["traceEvents"]
+    assert events and all(e["ph"] == "X" and "ts" in e and "dur" in e
+                          for e in events)
+    assert len({e["name"] for e in events}) >= 6
+    by_id = {r.span_id: r for r in spans}
+    children = [r for r in spans if r.parent_id is not None]
+    assert children
+    for r in children:
+        p = by_id[r.parent_id]
+        assert p.t0_s - 1e-6 <= r.t0_s
+        assert r.t0_s + r.dur_s <= p.t0_s + p.dur_s + 1e-6, (r.name, p.name)
+
+
 def test_untraced_session_records_nothing_but_metrics(tmp_path):
     sess = KishuSession(open_store(f"dir://{tmp_path}/cas"),
                         chunk_bytes=1 << 10)

@@ -220,6 +220,63 @@ def test_plan_matches_executed_paths():
     s.close()
 
 
+def _dirty_rate_session(path, mode, dirty_chunks):
+    """``w`` (64 chunks) dirtied in place, ``big`` derived from the
+    one-chunk ``seed``: the patch lane and the replay lane both apply."""
+    s = KishuSession(DirectoryStore(path), chunk_bytes=1 << 10,
+                     cache_bytes=0, plan_mode=mode)
+
+    def touch(ns, step):
+        for c in range(dirty_chunks):
+            ns["w"][c * 256] = np.float32(step * 1000 + c)
+
+    def derive(ns, scale):
+        ns["big"] = (np.arange(1 << 14, dtype=np.float32)
+                     + ns["seed"].sum()) * np.float32(scale)
+    s.register("touch", touch)
+    s.register("derive", derive)
+    s.init_state({"w": np.arange(1 << 14, dtype=np.float32),
+                  "seed": np.linspace(0.0, 1.0, 256).astype(np.float32)})
+    ids = []
+    for r in range(1, 4):
+        s.run("touch", step=r)
+        ids.append(s.run("derive", scale=float(r)))
+    return s, ids
+
+
+def _state(s):
+    return {n: np.asarray(s.ns[n]).tobytes() for n in s.ns.names()}
+
+
+@pytest.mark.parametrize("dirty_frac", [0.01, 0.1, 0.5])
+def test_planner_auto_matches_off_by_dirty_rate(dirty_frac, tmp_path):
+    """At every dirty rate, planner-auto restores what fetch-only
+    restores, both stores hold the same chunk keys, and the priced plan's
+    path counts are the executed checkout's ``covs_planned_*``."""
+    dirty_chunks = max(1, round(64 * dirty_frac))
+    runs = {}
+    for mode in ("off", "auto"):
+        path = str(tmp_path / mode)
+        s, ids = _dirty_rate_session(path, mode, dirty_chunks)
+        target, head = ids[0], ids[-1]
+        states = []
+        for _ in range(2):
+            if mode == "auto":
+                want = s.plan(target).counts()
+            st = s.checkout(target)
+            if mode == "auto":
+                assert want == {"fetch": st.covs_planned_fetch,
+                                "replay": st.covs_planned_replay,
+                                "patch": st.covs_planned_patch}
+            states.append(_state(s))
+            s.checkout(head)
+            states.append(_state(s))
+        s.close()
+        assert states[0] == states[2] and states[1] == states[3]
+        runs[mode] = (states, sorted(DirectoryStore(path).list_chunk_keys()))
+    assert runs["off"] == runs["auto"]
+
+
 def test_det_replay_prices_fetch_at_infinity():
     """DetReplay's skipped commits are unserializable: the planner must
     price fetch at infinity and still checkout bit-identically."""
